@@ -11,7 +11,8 @@ adapters (each of which is argparse -> RunSpec -> facade):
 Every ``train``/``serve`` flag set resolves to a RunSpec first and
 every ``bench`` subcommand to a BenchSpec (``--dump-spec`` prints it),
 so the CLI surface and the programmatic API (docs/api.md,
-docs/benchmarks.md) can never drift. ``bench`` needs the repo root on
+docs/benchmarks.md) can never drift. Every command runs with JAX's
+persistent compilation cache on (repro/launch/cache.py). ``bench`` needs the repo root on
 sys.path (run from the checkout, as ``benchmarks/`` sits next to
 ``src/``).
 """
@@ -40,6 +41,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print(USAGE, end="")
         return 0
     cmd, rest = argv[0], argv[1:]
+    if cmd not in ("train", "serve", "dryrun", "bench"):
+        print(f"python -m repro: unknown command {cmd!r}\n{USAGE}",
+              file=sys.stderr, end="")
+        return 2
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if cmd == "train":
         from repro.launch.train import main as run
 
@@ -62,10 +70,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             return 2
         sys.argv = ["benchmarks.run", *rest]
         run()
-    else:
-        print(f"python -m repro: unknown command {cmd!r}\n{USAGE}",
-              file=sys.stderr, end="")
-        return 2
     return 0
 
 

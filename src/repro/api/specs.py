@@ -109,16 +109,27 @@ def _spec(cls):
 class ModelSpec(_Spec):
     """Reference into the config registry (config/registry.py) plus the
     declarative SCT overrides a sweep needs: ``rank`` overrides
-    ``cfg.sct.rank``; ``spectral_mlp=False`` is the dense baseline."""
+    ``cfg.sct.rank``; ``spectral_mlp=False`` is the dense baseline.
+    ``dtype`` overrides the compute dtype: ``"float32"`` is the regime
+    the token-identity oracles hold in, since two programs that round
+    differently in bf16 can split a near-tie of the greedy argmax."""
     arch: str = "smollm2-1.7b"
     reduced: bool = False
     rank: Optional[int] = None
     spectral_mlp: Optional[bool] = None
+    dtype: Optional[str] = None
+
+    def __post_init__(self):
+        if self.dtype not in (None, "float32", "bfloat16"):
+            raise ValueError(f"model dtype {self.dtype!r}; options "
+                             f"float32|bfloat16")
 
     def config(self):
         from repro.config import get_config
 
         cfg = get_config(self.arch, reduced=self.reduced)
+        if self.dtype is not None:
+            cfg = cfg.replace(dtype=self.dtype)
         sct_kw = {}
         if self.rank is not None:
             sct_kw["rank"] = int(self.rank)
@@ -223,6 +234,8 @@ class ShardingSpec(_Spec):
     def mesh(self, cfg):
         import jax
 
+        from repro.launch.mesh import make_mesh
+
         n_dev = jax.device_count()
         if self.data is None and self.model is None:
             if n_dev <= 1:
@@ -232,7 +245,7 @@ class ShardingSpec(_Spec):
                 if n_dev % cand == 0 and cfg.d_ff % cand == 0:
                     n_model = cand
                     break
-            return jax.make_mesh((n_dev // n_model, n_model), ("data", "model"))
+            return make_mesh((n_dev // n_model, n_model), ("data", "model"))
         n_model = self.model or 1
         n_data = self.data or n_dev // n_model
         if n_data * n_model != n_dev:
@@ -240,7 +253,7 @@ class ShardingSpec(_Spec):
                              f"{n_data * n_model} devices, have {n_dev}")
         if n_data == n_model == 1:
             return None
-        return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((n_data, n_model), ("data", "model"))
 
 
 @_spec

@@ -136,8 +136,16 @@ class Trainer:
             step += 1
 
     def _init_state(self):
-        params = init_model(jax.random.PRNGKey(self.spec.train.seed), self.cfg)
-        return self.optimizer.init(params)
+        def init():
+            params = init_model(jax.random.PRNGKey(self.spec.train.seed),
+                                self.cfg)
+            return self.optimizer.init(params)
+
+        if self.state_shardings is None:
+            return init()
+        # born sharded: built eagerly, the whole state would first sit
+        # on one device, which a model sized for the mesh cannot fit
+        return jax.jit(init, out_shardings=self.state_shardings)()
 
     # ----------------------------------------------------------------- fit --
     def fit(self) -> Any:

@@ -9,7 +9,10 @@ def orthogonality_error(U: jax.Array) -> jax.Array:
     """max |U^T U - I| — the paper's reported 'Ortho. Error' metric
     (Table 2 reports < 2e-6 after retraction)."""
     Uf = U.astype(jnp.float32)
-    G = jnp.einsum("...mk,...ml->...kl", Uf, Uf)
+    # full fp32 products: at default precision a TPU rounds the operands
+    # to bf16, which alone leaves ~1e-3 and hides the retraction's error
+    G = jnp.einsum("...mk,...ml->...kl", Uf, Uf,
+                   precision=jax.lax.Precision.HIGHEST)
     eye = jnp.eye(G.shape[-1], dtype=G.dtype)
     return jnp.max(jnp.abs(G - eye))
 

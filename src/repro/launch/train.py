@@ -18,6 +18,7 @@ sharded state, jitted train_step with donation, fault-tolerant loop.
 from __future__ import annotations
 
 import argparse
+import tempfile
 from typing import Optional, Sequence
 
 from repro.api import (
@@ -60,7 +61,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--telemetry", action="store_true",
                     help="emit spectral telemetry (rank/* metrics) in the "
                          "train log even without a rank schedule")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory; one that already holds a "
+                         "checkpoint is resumed from (default: a fresh "
+                         "temporary directory, so a rerun starts over)")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--dump-spec", action="store_true",
@@ -84,10 +88,13 @@ def build_spec(args: argparse.Namespace) -> RunSpec:
 
 def main(argv: Optional[Sequence[str]] = None) -> None:
     args = build_parser().parse_args(argv)
-    spec = build_spec(args)
     if args.dump_spec:
-        print(spec.to_json(indent=2))
+        print(build_spec(args).to_json(indent=2))
         return
+    if args.ckpt_dir is None:
+        args.ckpt_dir = tempfile.mkdtemp(prefix="repro_ckpt_")
+        print(f"checkpoints: {args.ckpt_dir}")
+    spec = build_spec(args)
 
     trainer = Trainer(spec, metrics_cb=log_metrics)
     state = trainer.fit()

@@ -16,7 +16,6 @@ vmaps over E for free (retraction broadcasting, core/retraction.py).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional
 
 import jax
@@ -178,19 +177,9 @@ def apply_moe_sharded(p, x, cfg, *, capacity_factor: float = 1.25,
         out = jax.lax.psum(partial, "model")
         return out.reshape(bl, sl, d), aux
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
-    kw = dict(mesh=mesh, in_specs=(re_specs, x_spec), out_specs=(x_spec, P()))
-    # replication-check kwarg was renamed check_rep -> check_vma across
-    # jax versions; pass whichever this jax understands
-    sm_params = inspect.signature(shard_map).parameters
-    if "check_vma" in sm_params:
-        kw["check_vma"] = False
-    elif "check_rep" in sm_params:
-        kw["check_rep"] = False
-    out, aux = shard_map(f, **kw)(router_experts, x)
+    out, aux = jax.shard_map(
+        f, mesh=mesh, in_specs=(re_specs, x_spec), out_specs=(x_spec, P()),
+        check_vma=False)(router_experts, x)
 
     if cfg.n_shared_experts:
         from repro.nn.mlp import apply_mlp

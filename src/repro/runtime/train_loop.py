@@ -8,7 +8,10 @@ Production behaviours, all exercised by tests on CPU:
     a restarted run is bit-identical to an uninterrupted one.
   * failure injection: ``failure_hook`` lets tests (and chaos drills)
     raise mid-run; the loop converts unhandled step failures into a
-    clean checkpoint-backed restart up to ``max_restarts``.
+    clean checkpoint-backed restart up to ``max_restarts``. Errors the
+    runtime raises (``jax.errors.JaxRuntimeError``: out of device
+    memory, a refused compile, a lost device) re-raise at once — a
+    restart would only meet them again.
   * straggler mitigation: per-step deadline; steps that exceed it are
     counted and surfaced (on real multi-host this feeds the
     reschedule/evict policy; here it is monitored + tested).
@@ -70,6 +73,10 @@ class TrainLoop:
         self.failure_hook = failure_hook
         self.rank_controller = rank_controller
         self.straggler_steps = 0
+        # per executed step, in order (a restart appends its replays):
+        # host seconds to a ready loss, and that loss
+        self.step_seconds: list = []
+        self.losses: list = []
         self.restarts = 0
         self.rank_resizes = 0
         # mixed precision: overflow-skipped steps, mirrored from the
@@ -89,7 +96,9 @@ class TrainLoop:
         while True:
             try:
                 return self._run_once()
-            except Exception:  # noqa: BLE001 — any step failure
+            except jax.errors.JaxRuntimeError:
+                raise
+            except Exception:  # noqa: BLE001 — any other step failure
                 attempt += 1
                 self.restarts += 1
                 if attempt > self.cfg.max_restarts:
@@ -138,6 +147,8 @@ class TrainLoop:
             # straggler detection needs the actual step time
             jax.block_until_ready(metrics["loss"])
             dt = time.time() - t0
+            self.step_seconds.append(dt)
+            self.losses.append(float(metrics["loss"]))
             if self.cfg.step_deadline_s and dt > self.cfg.step_deadline_s:
                 self.straggler_steps += 1
             step += 1

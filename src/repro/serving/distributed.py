@@ -147,16 +147,16 @@ class PrefillWorker:
                 TP_AXIS,
                 named_shardings,
                 paged_state_pspecs,
-                shard_map_compat,
             )
 
             tp = int(mesh.shape[TP_AXIS])
             specs = paged_state_pspecs(cfg, self.state, tp)
-            self._chunk_fn = jax.jit(shard_map_compat(
+            self._chunk_fn = jax.jit(jax.shard_map(
                 lambda p, t, st, bt, s0: prefill_chunk_paged(
                     p, t, st, bt, s0, cfg, tp_axis=TP_AXIS, tp_size=tp),
-                mesh, in_specs=(P(), P(), specs, P(), P()),
-                out_specs=(P(), specs)), donate_argnums=(2,))
+                mesh=mesh, in_specs=(P(), P(), specs, P(), P()),
+                out_specs=(P(), specs), check_vma=False),
+                donate_argnums=(2,))
             self.state = jax.device_put(self.state,
                                         named_shardings(specs, mesh))
         else:

@@ -112,6 +112,9 @@ class ServingEngine:
         from repro.serving.quantize import param_bytes, quantize_tree
 
         self.weight_bytes_fp = param_bytes(params)
+        # one host-to-device copy now: host arrays (a checkpoint restore)
+        # would otherwise be re-sent on every jitted step
+        params = jax.device_put(params)
         if quantize == "int8":
             params = quantize_tree(params)
         elif quantize is not None:
@@ -214,7 +217,6 @@ class ServingEngine:
                 named_shardings,
                 paged_state_pspecs,
                 serve_tp_valid,
-                shard_map_compat,
             )
 
             self.tp = int(mesh.shape[TP_AXIS])
@@ -229,16 +231,21 @@ class ServingEngine:
         if mesh is not None and self.tp > 1:
             tp = self.tp
             state_specs = paged_state_pspecs(cfg, self.state, tp)
-            self._decode_fn = jax.jit(shard_map_compat(
+            # replication check off: logits and replicated pools are
+            # replicated by construction (every shard computes them
+            # from all-gathered head outputs)
+            self._decode_fn = jax.jit(jax.shard_map(
                 lambda p, t, st, bt, sl: decode_step_paged(
                     p, t, st, bt, sl, cfg, tp_axis=TP_AXIS, tp_size=tp),
-                mesh, in_specs=(P(), P(), state_specs, P(), P()),
-                out_specs=(P(), state_specs)), donate_argnums=(2,))
-            self._chunk_fn = jax.jit(shard_map_compat(
+                mesh=mesh, in_specs=(P(), P(), state_specs, P(), P()),
+                out_specs=(P(), state_specs), check_vma=False),
+                donate_argnums=(2,))
+            self._chunk_fn = jax.jit(jax.shard_map(
                 lambda p, t, st, bt, s0: prefill_chunk_paged(
                     p, t, st, bt, s0, cfg, tp_axis=TP_AXIS, tp_size=tp),
-                mesh, in_specs=(P(), P(), state_specs, P(), P()),
-                out_specs=(P(), state_specs)), donate_argnums=(2,))
+                mesh=mesh, in_specs=(P(), P(), state_specs, P(), P()),
+                out_specs=(P(), state_specs), check_vma=False),
+                donate_argnums=(2,))
             self.state = jax.device_put(self.state,
                                         named_shardings(state_specs, mesh))
             rep = NamedSharding(mesh, P())

@@ -3,12 +3,9 @@ for the training step and, since the distributed-serving refactor, the
 paged decode path (serve meshes, pool placement, shard_map wrapping)."""
 from __future__ import annotations
 
-import inspect
 from typing import Any
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config.model_config import ModelConfig
@@ -156,7 +153,9 @@ def serve_mesh(tp: int) -> Mesh:
             f"serve mesh needs {tp} devices, host has {len(devices)} "
             f"(force more on CPU with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={tp})")
-    return Mesh(np.array(devices[:tp]), (TP_AXIS,))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((tp,), (TP_AXIS,), devices=devices)
 
 
 def serve_tp_valid(cfg: ModelConfig, tp: int) -> bool:
@@ -190,20 +189,3 @@ def paged_state_pspecs(cfg: ModelConfig, state_like: Any, n_model: int) -> Any:
         return spec_for(path, tree)
 
     return walk(state_like)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: the replication-check kwarg
-    was renamed check_rep -> check_vma; disable it either way (the
-    decode step's logits/pools are replicated by construction — every
-    shard computes them from all-gathered head outputs)."""
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    sm_params = inspect.signature(shard_map).parameters
-    if "check_vma" in sm_params:
-        kw["check_vma"] = False
-    elif "check_rep" in sm_params:
-        kw["check_rep"] = False
-    return shard_map(f, **kw)

@@ -129,6 +129,18 @@ def test_model_spec_overrides_reach_config():
     assert plain.sct.rank != 8 and plain.sct.spectral_mlp is True
 
 
+def test_model_spec_dtype_override():
+    """``model.dtype`` reaches the config, survives a JSON round trip,
+    and rejects anything but the two compute dtypes."""
+    base = ModelSpec("smollm2-1.7b", reduced=True).config()
+    assert base.dtype == "bfloat16"
+    spec = RunSpec().replace(**{"model.dtype": "float32"})
+    assert spec.model.config().dtype == "float32"
+    assert RunSpec.from_json(spec.to_json()) == spec
+    with pytest.raises(ValueError, match="model dtype"):
+        ModelSpec(dtype="float16")
+
+
 def test_precision_spec_legacy_is_explicit():
     """The legacy path is a named mode, not a sentinel: the spec says
     'legacy', the optimizer-facing policy is None, and the effective

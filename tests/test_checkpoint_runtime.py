@@ -113,6 +113,24 @@ def test_too_many_failures_raises(tmp_path):
         loop.run()
 
 
+def test_device_error_is_not_retried(tmp_path):
+    """A runtime error from the device (here an out-of-memory refusal)
+    re-raises on its first occurrence: no restart, no second attempt."""
+    cfg = get_config("smollm2-1.7b", reduced=True)
+    calls = []
+
+    def oom(step):
+        calls.append(step)
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: Out of memory while trying to allocate")
+
+    loop = _make_loop(tmp_path, cfg, failure_hook=oom)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        loop.run()
+    assert loop.restarts == 0
+    assert calls == [0]
+
+
 def test_async_checkpoint_with_donated_state(tmp_path):
     """Async checkpointing must not race with buffer donation (the
     production launcher jits with donate_argnums=(0,)): the loop fetches
@@ -205,10 +223,12 @@ def test_elastic_reshard_roundtrip(tmp_path, key):
     (single-device) shardings — the elastic-scaling path."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh
+
     tree = {"w": jax.random.normal(key, (8, 4))}
     p = str(tmp_path / "ck.npz")
     save_pytree(tree, p)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P(None, None))}
     out = load_pytree(p, shardings=sh)
     assert tree_equal(tree, out)

@@ -42,13 +42,14 @@ def test_int8_roundtrip_and_error_feedback(key):
 def test_compressed_psum_single_device_is_identity_scale(key):
     """n=1 sanity inside shard_map: result equals the shard's own int8
     round-trip and the residual is exactly what the wire lost."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
 
     g = {"w": jax.random.normal(key, (64,)) * 3.0}
     ef = init_error_feedback(g)
-    mesh = Mesh(jax.devices()[:1], ("dp",))
-    out, new_ef = shard_map(
+    mesh = make_mesh((1,), ("dp",))
+    out, new_ef = jax.shard_map(
         lambda gg, rr: compressed_psum(gg, "dp", rr),
         mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
     )(g, ef)
@@ -64,8 +65,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import json
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.runtime.compression import (
     compress_int8, compressed_psum, init_error_feedback)
 
@@ -76,7 +77,7 @@ g = jnp.concatenate([jax.random.normal(k0, (1, 128)) * 1e-3,
                      jax.random.normal(k1, (1, 128)) * 1.0], axis=0)  # (2, 128)
 ref = jnp.mean(g, axis=0)                         # fp32 mean across "pods"
 
-mesh = Mesh(jax.devices()[:2], ("dp",))
+mesh = make_mesh((2,), ("dp",))
 
 def fixed(gg, rr):
     out, new_ef = compressed_psum({"w": gg}, "dp", rr)
@@ -91,10 +92,10 @@ def legacy(gg):
     return summed.astype(jnp.float32) * (scale_sum / n) / n
 
 ef = init_error_feedback({"w": g})
-out_fixed, new_ef = shard_map(
+out_fixed, new_ef = jax.shard_map(
     fixed, mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=(P("dp"), P("dp")),
 )(g, ef)
-out_legacy = shard_map(
+out_legacy = jax.shard_map(
     legacy, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
 )(g)
 

@@ -362,13 +362,14 @@ from repro.config import get_config
 from repro.config.shapes import ShapeSpec
 from repro.data.synthetic import SyntheticLMDataset
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.models.model import init_model
 from repro.optim import make_sct_optimizer
 from repro.rank import RankController, StepRankSchedule, current_ranks
 from repro.sharding.rules import set_current_mesh
 
 cfg = get_config("smollm2-1.7b", reduced=True)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 set_current_mesh(mesh)
 shape = ShapeSpec("t", 16, 8, "train")
 opt = make_sct_optimizer(cfg, lr=1e-3, warmup=2, total_steps=8)

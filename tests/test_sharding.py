@@ -73,9 +73,10 @@ import jax, json
 from repro.config import get_config, SHAPES
 from repro.config.shapes import ShapeSpec
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 
 cfg = get_config("{arch}", reduced=True)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shape = ShapeSpec("t", 64, 8, "{kind}")
 lowered = steps_mod.lower_step(cfg, shape, mesh)
 compiled = lowered.compile()
@@ -103,3 +104,53 @@ def test_small_mesh_dryrun_compiles(arch, kind):
     assert out.returncode == 0, out.stderr[-2000:]
     payload = json.loads(out.stdout.strip().splitlines()[-1])
     assert payload["flops"] > 0
+
+
+_SUBPROCESS_TRAINER_INIT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, json
+import numpy as np
+from repro.api import ModelSpec, RunSpec, TrainSpec, Trainer
+from repro.models.model import init_model
+
+spec = RunSpec(model=ModelSpec("smollm2-1.7b", reduced=True),
+               train=TrainSpec(steps=1, batch=4, seq=16))
+trainer = Trainer(spec)
+state = trainer._init_state()
+leaves = jax.tree.leaves(state)
+per_device = {d.id: 0 for d in jax.devices()}
+for x in leaves:
+    for shard in x.addressable_shards:
+        per_device[shard.device.id] += shard.data.nbytes
+eager = trainer.optimizer.init(
+    init_model(jax.random.PRNGKey(spec.train.seed), trainer.cfg))
+print(json.dumps({
+    "mesh": trainer.mesh.size,
+    "as_specified": all(
+        x.sharding.is_equivalent_to(s, x.ndim)
+        for x, s in zip(leaves, jax.tree.leaves(trainer.state_shardings))),
+    "per_device": list(per_device.values()),
+    "total": sum(x.nbytes for x in leaves),
+    "max_diff": max(float(np.max(np.abs(np.asarray(a, np.float64)
+                                        - np.asarray(b, np.float64))))
+                    for a, b in zip(leaves, jax.tree.leaves(eager))),
+}))
+"""
+
+
+def test_trainer_mesh_state_born_sharded():
+    """On the Trainer's automatic 4-device mesh the initial train state
+    is created with the step's shardings: no device ever holds the whole
+    state, and the values are the eager single-device init's."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", _SUBPROCESS_TRAINER_INIT],
+                         capture_output=True, text=True, timeout=600, env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    payload = json.loads(out.stdout.strip().splitlines()[-1])
+    assert payload["mesh"] == 4
+    assert payload["as_specified"]
+    assert len(payload["per_device"]) == 4
+    assert max(payload["per_device"]) < payload["total"]
+    assert payload["max_diff"] <= 1e-6

@@ -1,0 +1,122 @@
+"""Compile-only tests of the main path's Pallas kernels for a TPU v5e
+that is described, not attached.
+
+Interpret mode runs a kernel's math on the CPU but never asks the TPU
+compiler, so a block shape it refuses (the tiling rule on the last two
+block dimensions) or a VMEM budget it cannot meet passes every
+interpret-mode test. Here each kernel is lowered and compiled at
+published widths for one chip of a described ``v5e:2x2`` topology, and
+the compiled program must call the kernel (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never at
+import time: only one process at a time may load the TPU library, and
+every test worker imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_decode import (
+    paged_gqa_decode_cold_pallas,
+    paged_gqa_decode_pallas,
+    paged_mla_decode_cold_pallas,
+    paged_mla_decode_pallas,
+)
+from repro.kernels.spectral_matmul import (
+    DEFAULT_BM,
+    DEFAULT_CM,
+    DEFAULT_CN,
+    spectral_matmul_pallas,
+)
+from repro.kernels.spectral_matmul_q8 import spectral_matmul_q8_pallas
+
+# serving geometry of chip_smoke.py: 4 slots, page 16, 32 pages/seq
+SLOTS, PAGE, PAGES_PER_SEQ, NUM_PAGES = 4, 16, 32, 128
+# smollm2-1.7b attention: 32 kv heads (MHA), head dim 64
+GQA_KVH, GQA_REP, GQA_HD = 32, 1, 64
+# DeepSeek-V3 absorbed MLA, one chip's 16 query heads over the latent
+MLA_H, MLA_LAT, MLA_ROPE = 16, 512, 64
+# smollm2-1.7b MLP projection 2048 -> 8192 at the paper's rank 128
+MLP_M, MLP_N, MLP_K, MLP_TOKENS = 2048, 8192, 128, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else the compiler logs to /tmp
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, one_chip, *shapes) -> str:
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _paged_geometry():
+    return [((SLOTS, PAGES_PER_SEQ), jnp.int32), ((SLOTS,), jnp.int32)]
+
+
+@pytest.mark.parametrize("variant", ["hot", "cold"])
+def test_paged_gqa_decode_compiles(one_chip, variant):
+    q = ((SLOTS, GQA_KVH, GQA_REP, GQA_HD), jnp.bfloat16)
+    pool = ((NUM_PAGES + 1, PAGE, GQA_KVH, GQA_HD), jnp.bfloat16)
+    if variant == "hot":
+        fn = lambda *a: paged_gqa_decode_pallas(*a, interpret=False)
+        shapes = [q, pool, pool, *_paged_geometry()]
+    else:
+        fn = lambda *a: paged_gqa_decode_cold_pallas(*a, interpret=False)
+        q8 = ((NUM_PAGES + 1, PAGE, GQA_KVH, GQA_HD), jnp.int8)
+        scale = ((NUM_PAGES + 1, GQA_KVH, GQA_HD), jnp.float32)
+        shapes = [q, pool, pool, q8, scale, q8, scale, *_paged_geometry(),
+                  ((NUM_PAGES + 1,), jnp.int32)]
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("variant", ["hot", "cold"])
+def test_paged_mla_decode_compiles(one_chip, variant):
+    q_lat = ((SLOTS, MLA_H, MLA_LAT), jnp.bfloat16)
+    q_rope = ((SLOTS, MLA_H, MLA_ROPE), jnp.bfloat16)
+    ckv = ((NUM_PAGES + 1, PAGE, MLA_LAT), jnp.bfloat16)
+    kr = ((NUM_PAGES + 1, PAGE, MLA_ROPE), jnp.bfloat16)
+    scale = 1.0 / (128 + MLA_ROPE) ** 0.5
+    if variant == "hot":
+        fn = lambda *a: paged_mla_decode_pallas(*a, scale=scale,
+                                                interpret=False)
+        shapes = [q_lat, q_rope, ckv, kr, *_paged_geometry()]
+    else:
+        fn = lambda *a: paged_mla_decode_cold_pallas(*a, scale=scale,
+                                                     interpret=False)
+        shapes = [q_lat, q_rope, ckv, kr,
+                  ((NUM_PAGES + 1, PAGE, MLA_LAT), jnp.int8),
+                  ((NUM_PAGES + 1, MLA_LAT), jnp.float32),
+                  ((NUM_PAGES + 1, PAGE, MLA_ROPE), jnp.int8),
+                  ((NUM_PAGES + 1, MLA_ROPE), jnp.float32),
+                  *_paged_geometry(), ((NUM_PAGES + 1,), jnp.int32)]
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("variant", ["fp", "q8"])
+def test_spectral_matmul_compiles(one_chip, variant):
+    tiles = dict(bm=DEFAULT_BM, cm=DEFAULT_CM, cn=DEFAULT_CN, interpret=False)
+    x = ((MLP_TOKENS, MLP_M), jnp.bfloat16)
+    scale = ((MLP_K,), jnp.float32)
+    if variant == "fp":
+        fn = lambda *a: spectral_matmul_pallas(*a, **tiles)
+        factor = jnp.bfloat16
+    else:
+        fn = lambda *a: spectral_matmul_q8_pallas(*a, **tiles)
+        factor = jnp.int8
+    shapes = [x, ((MLP_M, MLP_K), factor), scale, ((MLP_N, MLP_K), factor)]
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
