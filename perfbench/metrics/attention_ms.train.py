@@ -1,0 +1,13 @@
+"""Device milliseconds per traced training step in the attention layers
+(scope ``sct.attention``: pre-attention norm, attention, residual add;
+forward, backward and recompute), by ``perfbench/scopes.py``. Moves
+``train_tokens_per_s``."""
+import os
+
+from perfbench import scopes
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def read(ctx):
+    return scopes.scope_ms(ctx, ROOT, "sct.attention")

@@ -29,6 +29,7 @@ import numpy as np
 from repro.api.specs import RunSpec
 from repro.checkpoint.manager import CheckpointManager
 from repro.config.shapes import ShapeSpec
+from repro.core import scopes
 from repro.data.synthetic import SyntheticLMDataset
 from repro.launch import steps as steps_mod
 from repro.models.model import init_model
@@ -221,11 +222,14 @@ class Trainer:
         schedules fire at the same step boundaries as in :meth:`fit`."""
         self._ensure_state()
         if batch is None:
-            batch = next(self._batches)
-        self._state, metrics = self._step_fn(self._state, batch)
+            with jax.profiler.TraceAnnotation(scopes.BATCH):
+                batch = next(self._batches)
+        with jax.profiler.TraceAnnotation(scopes.DISPATCH):
+            self._state, metrics = self._step_fn(self._state, batch)
         self._step += 1
         if self.controller is not None:
-            result = self.controller.maybe_resize(self._step, self._state, metrics)
+            with jax.profiler.TraceAnnotation(scopes.RANK_RESIZE):
+                result = self.controller.maybe_resize(self._step, self._state, metrics)
             if result is not None:
                 self._state, self._step_fn, self.state_shardings = result
         return metrics

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.model_config import ModelConfig
+from repro.core import scopes
 from repro.nn import attention as attn
 from repro.nn import mamba as mamba_mod
 from repro.nn import xlstm as xlstm_mod
@@ -163,49 +164,56 @@ def init_lm(key, cfg: ModelConfig) -> Params:
 # Forward (training / no-cache)
 # ======================================================================
 
+def _attention_residual(cfg, p, x, positions):
+    with jax.named_scope(scopes.ATTENTION):
+        h = _norm_apply(cfg, p["attn_norm"], x)
+        if cfg.attention == "mla":
+            h = attn.apply_mla(p["attn"], h, cfg, positions=positions)
+        else:
+            h = attn.apply_gqa(p["attn"], h, cfg, positions=positions,
+                               use_pallas=cfg.use_pallas)
+        return x + h
+
+
 def _dense_block(cfg, p, x, positions):
-    h = _norm_apply(cfg, p["attn_norm"], x)
-    if cfg.attention == "mla":
-        h = attn.apply_mla(p["attn"], h, cfg, positions=positions)
-    else:
-        h = attn.apply_gqa(p["attn"], h, cfg, positions=positions, use_pallas=cfg.use_pallas)
-    x = x + h
-    h = _norm_apply(cfg, p["mlp_norm"], x)
-    h = apply_mlp(p["mlp"], h, act=cfg.act, use_pallas=cfg.use_pallas)
-    return x + h
+    x = _attention_residual(cfg, p, x, positions)
+    with jax.named_scope(scopes.MLP):
+        h = _norm_apply(cfg, p["mlp_norm"], x)
+        h = apply_mlp(p["mlp"], h, act=cfg.act, use_pallas=cfg.use_pallas)
+        return x + h
 
 
 def _moe_block(cfg, p, x, positions):
-    h = _norm_apply(cfg, p["attn_norm"], x)
-    if cfg.attention == "mla":
-        h = attn.apply_mla(p["attn"], h, cfg, positions=positions)
-    else:
-        h = attn.apply_gqa(p["attn"], h, cfg, positions=positions, use_pallas=cfg.use_pallas)
-    x = x + h
-    h = _norm_apply(cfg, p["mlp_norm"], x)
-    h, aux = apply_moe(p["moe"], h, cfg, capacity_factor=cfg.capacity_factor,
-                       use_pallas=cfg.use_pallas)
-    return x + h, aux
+    x = _attention_residual(cfg, p, x, positions)
+    with jax.named_scope(scopes.MLP):
+        h = _norm_apply(cfg, p["mlp_norm"], x)
+        h, aux = apply_moe(p["moe"], h, cfg, capacity_factor=cfg.capacity_factor,
+                           use_pallas=cfg.use_pallas)
+        return x + h, aux
 
 
 def _hybrid_period_fwd(cfg, pp, x, positions):
     aux_total = jnp.float32(0.0)
     for p in range(cfg.attn_every):
         lp = pp[f"p{p}"]
-        h = _norm_apply(cfg, lp["pre_norm"], x)
         if "attn" in lp:
-            h = attn.apply_gqa(lp["attn"], h, cfg, positions=positions, use_pallas=cfg.use_pallas)
+            with jax.named_scope(scopes.ATTENTION):
+                h = _norm_apply(cfg, lp["pre_norm"], x)
+                h = attn.apply_gqa(lp["attn"], h, cfg, positions=positions,
+                                   use_pallas=cfg.use_pallas)
+                x = x + h
         else:
-            h = mamba_mod.apply_mamba(lp["mamba"], h, cfg)
-        x = x + h
-        h = _norm_apply(cfg, lp["ff_norm"], x)
-        if "moe" in lp:
-            h, aux = apply_moe(lp["moe"], h, cfg, capacity_factor=cfg.capacity_factor,
-                               use_pallas=cfg.use_pallas)
-            aux_total = aux_total + aux
-        else:
-            h = apply_mlp(lp["mlp"], h, act=cfg.act, use_pallas=cfg.use_pallas)
-        x = x + h
+            h = _norm_apply(cfg, lp["pre_norm"], x)
+            x = x + mamba_mod.apply_mamba(lp["mamba"], h, cfg)
+        with jax.named_scope(scopes.MLP):
+            h = _norm_apply(cfg, lp["ff_norm"], x)
+            if "moe" in lp:
+                h, aux = apply_moe(lp["moe"], h, cfg, capacity_factor=cfg.capacity_factor,
+                                   use_pallas=cfg.use_pallas)
+                aux_total = aux_total + aux
+            else:
+                h = apply_mlp(lp["mlp"], h, act=cfg.act, use_pallas=cfg.use_pallas)
+            x = x + h
     return x, aux_total
 
 
@@ -238,7 +246,8 @@ def forward_lm(params: Params, tokens: jax.Array, cfg: ModelConfig) -> Tuple[jax
     """tokens (b, s) -> (logits (b, s, vocab) fp32-castable, aux_loss)."""
     b, s = tokens.shape
     dt = _compute_dtype(cfg)
-    x = constrain_activation(apply_embedding(params["embed"], tokens, compute_dtype=dt))
+    with jax.named_scope(scopes.EMBED):
+        x = constrain_activation(apply_embedding(params["embed"], tokens, compute_dtype=dt))
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     aux = jnp.float32(0.0)
 
@@ -264,8 +273,9 @@ def forward_lm(params: Params, tokens: jax.Array, cfg: ModelConfig) -> Tuple[jax
     else:
         raise ValueError(cfg.family)
 
-    x = _norm_apply(cfg, params["final_norm"], x)
-    logits = apply_lm_head(params["embed"], x)
+    with jax.named_scope(scopes.HEAD):
+        x = _norm_apply(cfg, params["final_norm"], x)
+        logits = apply_lm_head(params["embed"], x)
     return logits, aux
 
 
@@ -313,6 +323,7 @@ def cross_entropy(logits: jax.Array, labels: jax.Array, z_loss: float = 1e-4):
 
 def train_loss_lm(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig):
     logits, aux = forward_lm(params, batch["tokens"], cfg)
-    loss = cross_entropy(logits, batch["labels"])
-    total = loss + cfg.aux_loss_coef * aux
+    with jax.named_scope(scopes.HEAD):
+        loss = cross_entropy(logits, batch["labels"])
+        total = loss + cfg.aux_loss_coef * aux
     return total, {"ce_loss": loss, "aux_loss": aux}

@@ -32,6 +32,7 @@ from repro.core.precision import (
     precision_policy,
     unscale_grads,
 )
+from repro.core import scopes
 from repro.core.tree import retract_tree
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.optim.clip import clip_by_global_norm
@@ -67,19 +68,21 @@ class SCTOptimizer:
         """One AdamW step + (conditional) retraction. ``step`` is the
         pre-increment counter: the schedule reads it, the retraction
         cadence checks step+1 — both exactly as the fp32 path always did."""
-        lr_t = make_schedule(self.schedule)(step)
-        if self.clip_norm:
-            grads, _ = clip_by_global_norm(grads, self.clip_norm)
-        params, opt = adamw_update(params, grads, opt, self.adamw, lr_t)
-        if self.retract_every == 1:
-            params = retract_tree(params, self.retraction, self.retract_axis_name)
-        else:
-            params = jax.lax.cond(
-                (step + 1) % self.retract_every == 0,
-                lambda p: retract_tree(p, self.retraction, self.retract_axis_name),
-                lambda p: p,
-                params,
-            )
+        with jax.named_scope(scopes.OPTIMIZER):
+            lr_t = make_schedule(self.schedule)(step)
+            if self.clip_norm:
+                grads, _ = clip_by_global_norm(grads, self.clip_norm)
+            params, opt = adamw_update(params, grads, opt, self.adamw, lr_t)
+        with jax.named_scope(scopes.RETRACTION):
+            if self.retract_every == 1:
+                params = retract_tree(params, self.retraction, self.retract_axis_name)
+            else:
+                params = jax.lax.cond(
+                    (step + 1) % self.retract_every == 0,
+                    lambda p: retract_tree(p, self.retraction, self.retract_axis_name),
+                    lambda p: p,
+                    params,
+                )
         return params, opt
 
     def resize(self, key: jax.Array, state: TrainState, target) -> TrainState:
@@ -108,8 +111,9 @@ class SCTOptimizer:
 
         # mixed path: grads arrive scaled by state["loss_scale"]["scale"]
         ls = state["loss_scale"]
-        grads = unscale_grads(grads, ls)
-        finite = all_finite(grads)
+        with jax.named_scope(scopes.OPTIMIZER):
+            grads = unscale_grads(grads, ls)
+            finite = all_finite(grads)
         params, opt = jax.lax.cond(
             finite,
             lambda p, o, g: self._update(p, o, state["step"], g),

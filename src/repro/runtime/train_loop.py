@@ -34,6 +34,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.core import scopes
 
 
 @dataclasses.dataclass
@@ -123,7 +124,8 @@ class TrainLoop:
         abandoned — jit keeps them cached but they are never called)."""
         if self.rank_controller is None:
             return state
-        result = self.rank_controller.maybe_resize(step, state, metrics)
+        with jax.profiler.TraceAnnotation(scopes.RANK_RESIZE):
+            result = self.rank_controller.maybe_resize(step, state, metrics)
         if result is None:
             return state
         state, self.step_fn, self.state_shardings = result
@@ -139,29 +141,40 @@ class TrainLoop:
         batches = self.batch_iter_factory(start_step)
         step = start_step
         while step < self.cfg.total_steps:
-            batch = next(batches)
-            t0 = time.time()
-            if self.failure_hook is not None:
-                self.failure_hook(step)
-            state, metrics = self.step_fn(state, batch)
-            # straggler detection needs the actual step time
-            jax.block_until_ready(metrics["loss"])
-            dt = time.time() - t0
-            self.step_seconds.append(dt)
-            self.losses.append(float(metrics["loss"]))
-            if self.cfg.step_deadline_s and dt > self.cfg.step_deadline_s:
-                self.straggler_steps += 1
-            step += 1
-            state = self._apply_rank_decision(step, state, metrics)
-            if self.metrics_cb and step % self.cfg.log_every == 0:
-                self.metrics_cb(step, {k: float(np.asarray(v)) for k, v in metrics.items()})
-            if step % self.cfg.checkpoint_every == 0 or step == self.cfg.total_steps:
-                # fetch to host *before* handing off to the async writer:
-                # the next step donates these device buffers (train.py
-                # jits with donate_argnums), and a save thread reading
-                # them after donation sees deleted arrays
-                self.mgr.save(step, jax.device_get(state))
-        self.mgr.wait()
+            with jax.profiler.StepTraceAnnotation(scopes.TRAIN_STEP, step_num=step):
+                with jax.profiler.TraceAnnotation(scopes.BATCH):
+                    batch = next(batches)
+                # a monotonic clock: a wall-clock jump would corrupt
+                # step_seconds and the straggler count
+                t0 = time.perf_counter()
+                if self.failure_hook is not None:
+                    self.failure_hook(step)
+                with jax.profiler.TraceAnnotation(scopes.DISPATCH):
+                    state, metrics = self.step_fn(state, batch)
+                with jax.profiler.TraceAnnotation(scopes.LOSS_SYNC):
+                    # straggler detection needs the actual step time
+                    jax.block_until_ready(metrics["loss"])
+                    dt = time.perf_counter() - t0
+                    loss = float(metrics["loss"])
+                self.step_seconds.append(dt)
+                self.losses.append(loss)
+                if self.cfg.step_deadline_s and dt > self.cfg.step_deadline_s:
+                    self.straggler_steps += 1
+                step += 1
+                state = self._apply_rank_decision(step, state, metrics)
+                if self.metrics_cb and step % self.cfg.log_every == 0:
+                    self.metrics_cb(step, {k: float(np.asarray(v))
+                                           for k, v in metrics.items()})
+                if step % self.cfg.checkpoint_every == 0 or step == self.cfg.total_steps:
+                    with jax.profiler.TraceAnnotation(scopes.CHECKPOINT):
+                        # fetch to host *before* handing off to the async
+                        # writer: the next step donates these device
+                        # buffers (train.py jits with donate_argnums), and
+                        # a save thread reading them after donation sees
+                        # deleted arrays
+                        self.mgr.save(step, jax.device_get(state))
+        with jax.profiler.TraceAnnotation(scopes.CHECKPOINT):
+            self.mgr.wait()
         if isinstance(state, dict) and "loss_scale" in state:
             # derived once at the end, not per step — no extra host
             # readback in the hot loop
