@@ -2,11 +2,11 @@
 
 Grid: (batch*kv_groups*rep, q_chunks). Each program streams kv chunks
 for one (batch, head, q-chunk) tile with online softmax — scores/probs
-never leave VMEM. This is the production TPU path for the attention
-layers; the jnp fallback in nn/attention.py (same math, same chunking)
-is what the 512-device dry-run partitions, and the roofline substitutes
-this kernel's HBM traffic for the fallback's (roofline/hlo_cost.py
-KERNEL_SCOPES) — see DESIGN.md S6.
+never leave VMEM. Forward only, with no causal block skipping, and no
+model path calls it: on TPU the training attention runs the bundled
+splash-attention kernel (nn/attention.py ``_sdpa_fused``). The
+roofline still substitutes this kernel's HBM traffic for the jnp
+``_flash`` fallback's (roofline/hlo_cost.py, ``PALLAS_EQ`` scopes).
 
 VMEM at cq=512, ck=1024, d=128, bf16 in / fp32 acc:
 q 128K + k/v 2x256K + scores 2MB (f32) + acc 256K ~= 3 MB << 16 MB.

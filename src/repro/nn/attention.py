@@ -13,15 +13,20 @@ Cache layouts (per layer, stacked with a leading L axis by the model):
 """
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as splash, splash_attention_mask as splash_masks)
 
+from repro.kernels.interpret import resolve_interpret
 from repro.nn.linear import init_linear, apply_linear
 from repro.nn.norms import init_rmsnorm, apply_rmsnorm
 from repro.nn.rotary import apply_rope, apply_mrope
+from repro.sharding import rules
 
 NEG_INF = -1e30
 
@@ -39,10 +44,104 @@ def _tp_slice(a, tp_axis, n_local, axis):
 
 FLASH_THRESHOLD = 2048  # direct softmax below this sequence length
 # big chunks: few loop iterations => few HBM round-trips of the chunk
-# intermediates in the XLA fallback (a Pallas flash kernel keeps them in
-# VMEM; see kernels/flash_attention.py and EXPERIMENTS.md §Perf)
+# intermediates in the jnp fallback, which still writes them to HBM
 FLASH_Q_CHUNK = 2048
 FLASH_KV_CHUNK = 4096
+
+# Fused causal kernel (splash attention, forward and one dQ/dK/dV
+# backward kernel): each block is the largest of these halved down to
+# 128 that divides the sequence. Chosen on a v5e at 4 x 2048 x 32 heads
+# x 64 against block_q 256/512, block_kv 512/2048 and the split dQ +
+# dK/dV backward (PERF.md §5).
+FUSED_MIN_SEQ = 128
+FUSED_BLOCK_Q = 1024
+FUSED_BLOCK_KV = 1024
+FUSED_BLOCK_KV_COMPUTE = 512
+
+# _sdpa calls by path ("fused", "chunked", "direct"), counted at trace time
+_PATH_COUNTS: collections.Counter = collections.Counter()
+
+
+def attention_path_counts() -> dict:
+    """How many traced ``_sdpa`` calls took each path since the last
+    :func:`reset_attention_path_counts`."""
+    return dict(_PATH_COUNTS)
+
+
+def reset_attention_path_counts() -> None:
+    _PATH_COUNTS.clear()
+
+
+def fused_kernel_backend() -> bool:
+    """Whether the fused causal kernel can run here: it compiles for TPU
+    only. Tests force it, together with interpret mode."""
+    return jax.default_backend() == "tpu"
+
+
+def _fused_block(s: int, target: int) -> int:
+    b = target
+    while s % b:
+        b //= 2
+    return b
+
+
+def _fused_block_sizes(s: int):
+    """Blocks for sequence ``s``. q, k and v enter the kernel with the
+    sequence minor: a head dim of 64 minor would be padded to 128 lanes
+    in HBM, and XLA would give the rope and projections feeding the
+    kernel that padded layout too (PERF.md §5)."""
+    bq = _fused_block(s, FUSED_BLOCK_Q)
+    bkv = _fused_block(s, FUSED_BLOCK_KV)
+    bkvc = _fused_block(bkv, FUSED_BLOCK_KV_COMPUTE)
+    seq_minor = splash.QKVLayout.SEQ_MINOR
+    return splash.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkvc,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkvc,
+        use_fused_bwd_kernel=True,
+        q_layout=seq_minor, k_layout=seq_minor, v_layout=seq_minor)
+
+
+@functools.lru_cache(maxsize=32)
+def _fused_kernel(heads: int, s: int, interpret: bool, block_sizes):
+    """The causal splash-attention kernel for ``heads`` query heads at
+    sequence ``s``, built once per shape: the mask's block tables are
+    host constants, made outside any trace."""
+    mask = splash_masks.MultiHeadMask([splash_masks.CausalMask((s, s))] * heads)
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(mask, block_sizes=block_sizes, head_shards=1,
+                                      q_seq_shards=1, interpret=interpret)
+
+
+def _fits_fused(q, k, v, *, causal, q_offset, kv_len_mask) -> bool:
+    """Causal self-attention at an aligned length on one TPU device.
+    q: (b, sq, h, d); k/v: (b, skv, kvh, d)."""
+    sq, d = q.shape[1], q.shape[-1]
+    mesh = rules.current_mesh()
+    return (
+        causal
+        and kv_len_mask is None
+        and isinstance(q_offset, int) and q_offset == 0
+        and sq == k.shape[1]
+        and sq >= FUSED_MIN_SEQ and sq % FUSED_MIN_SEQ == 0
+        and k.shape[-1] == d and v.shape[-1] == d     # MLA: dv != d
+        and (mesh is None or mesh.size <= 1)
+        and fused_kernel_backend()
+    )
+
+
+def _sdpa_fused(q, k, v):
+    """Causal attention through the fused kernel, forward and backward:
+    scores and probabilities stay in VMEM, kv blocks above the diagonal
+    are skipped. Query head i reads kv head i // (h // kvh), as the
+    grouped einsums do; K/V are never repeated. The kernel does not
+    scale, so 1/sqrt(d) is folded into q (rounded once to q's dtype).
+    q: (b, s, h, d); k/v: (b, s, kvh, d)."""
+    b, s, h, d = q.shape
+    kernel = _fused_kernel(h, s, resolve_interpret(None), _fused_block_sizes(s))
+    qs = (q.astype(jnp.float32) * (1.0 / d ** 0.5)).astype(q.dtype)
+    heads_first = lambda t: jnp.swapaxes(t, 1, 2)          # (b, heads, s, d)
+    out = jax.vmap(kernel)(heads_first(qs), heads_first(k), heads_first(v))
+    return jnp.swapaxes(out, 1, 2)
 
 
 def _sdpa_direct(q, k, v, *, causal: bool, q_offset=0, kv_len_mask=None):
@@ -81,9 +180,10 @@ def _flash_vjp_bwd(causal, res, dout):
 def _flash_fwd_impl(q, k, v, causal):
     """Exact flash-style attention in pure jnp: lax.map over q chunks,
     lax.scan over kv chunks with online softmax. Peak live scores tensor
-    is (b, g, r, cq, ck) instead of (b, g, r, s, s). On TPU this region
-    runs as the fused kernels/flash_attention.py kernel; this jnp
-    equivalent is what the 512-device dry-run partitions.
+    is (b, g, r, cq, ck) instead of (b, g, r, s, s), but each chunk's
+    scores still go through HBM. It runs wherever :func:`_sdpa` does not
+    take the fused kernel above FLASH_THRESHOLD (non-causal, more than
+    one device, not TPU); the 512-device dry-run partitions it.
     q: (b, sq, g, r, d); k/v: (b, skv, g, d).
     Returns (out, m, l) — the softmax stats the backward needs."""
     b, sq, g, r, d = q.shape
@@ -215,8 +315,17 @@ def _sdpa(q, k, v, *, causal: bool, q_offset=0, kv_len_mask=None):
     einsums — kv heads are never materialized repeated (a rep x HBM-
     traffic save over jnp.repeat). Softmax in fp32. causal uses absolute
     positions (q_offset for decode); kv_len_mask: (b, skv) valid slots.
+
+    Three paths, chosen from what is seen here: the fused kernel for
+    causal self-attention at an aligned length on one TPU device
+    (:func:`_fits_fused`); the chunked jnp ``_flash`` above
+    FLASH_THRESHOLD; else the direct softmax (decode, traced-offset
+    prefill, masks, short or unaligned sequences).
     """
     b, sq, h, d = q.shape
+    if _fits_fused(q, k, v, causal=causal, q_offset=q_offset, kv_len_mask=kv_len_mask):
+        _PATH_COUNTS["fused"] += 1
+        return _sdpa_fused(q, k, v)
     skv, kvh = k.shape[1], k.shape[2]
     rep = h // kvh
     qg = q.reshape(b, sq, kvh, rep, d)
@@ -231,13 +340,14 @@ def _sdpa(q, k, v, *, causal: bool, q_offset=0, kv_len_mask=None):
         and q_offset == 0               # => direct path, mask handles it
     )
     if use_flash:
-        # PALLAS_EQ marker: on TPU this region runs as the fused
-        # kernels/flash_attention.py kernel (validated against the same
-        # math); the roofline cost model substitutes the kernel's HBM
-        # traffic for the XLA fallback's (roofline/hlo_cost.py).
+        _PATH_COUNTS["chunked"] += 1
+        # PALLAS_EQ marker: the roofline cost model substitutes the HBM
+        # traffic of kernels/flash_attention.py for this jnp region's
+        # (roofline/hlo_cost.py), though that kernel does not run here.
         with jax.named_scope("PALLAS_EQ_flash_attention"):
             out = _flash(qg, k, v, causal)
     else:
+        _PATH_COUNTS["direct"] += 1
         out = _sdpa_direct(qg, k, v, causal=causal, q_offset=q_offset,
                            kv_len_mask=kv_len_mask)
     return out.reshape(b, sq, h, dv)

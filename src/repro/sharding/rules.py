@@ -136,6 +136,11 @@ def set_current_mesh(mesh) -> None:
     _CURRENT_MESH = mesh
 
 
+def current_mesh():
+    """The mesh set by :func:`set_current_mesh`, or None."""
+    return _CURRENT_MESH
+
+
 def set_activation_seq_sharding(axis: Optional[str]) -> None:
     """Sequence-parallelism knob: shard layer-boundary activations'
     sequence axis over ``axis`` ('model'). Cuts per-device activation

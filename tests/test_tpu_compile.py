@@ -30,6 +30,8 @@ from repro.kernels.spectral_matmul import (
     spectral_matmul_pallas,
 )
 from repro.kernels.spectral_matmul_q8 import spectral_matmul_q8_pallas
+from repro.nn import attention as attn
+from repro.sharding import rules
 
 # serving geometry of chip_smoke.py: 4 slots, page 16, 32 pages/seq
 SLOTS, PAGE, PAGES_PER_SEQ, NUM_PAGES = 4, 16, 32, 128
@@ -39,6 +41,8 @@ GQA_KVH, GQA_REP, GQA_HD = 32, 1, 64
 MLA_H, MLA_LAT, MLA_ROPE = 16, 512, 64
 # smollm2-1.7b MLP projection 2048 -> 8192 at the paper's rank 128
 MLP_M, MLP_N, MLP_K, MLP_TOKENS = 2048, 8192, 128, 512
+# smollm2-1.7b training attention: 4 packed rows of 2048, 32 heads of 64
+TRAIN_B, TRAIN_S, TRAIN_H, TRAIN_HD = 4, 2048, 32, 64
 
 
 @pytest.fixture(scope="module")
@@ -120,3 +124,39 @@ def test_spectral_matmul_compiles(one_chip, variant):
         factor = jnp.int8
     shapes = [x, ((MLP_M, MLP_K), factor), scale, ((MLP_N, MLP_K), factor)]
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+def _train_attention(fused: bool, grad: bool):
+    def attend(q, k, v):
+        if fused:
+            return attn._sdpa(q, k, v, causal=True)
+        b, s, h, d = q.shape
+        return attn._sdpa_direct(q.reshape(b, s, h, 1, d), k, v,
+                                 causal=True).reshape(b, s, h, d)
+
+    if not grad:
+        return attend
+    loss = lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) ** 2)
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+def test_fused_train_attention_compiles(one_chip, grad, monkeypatch):
+    """The training step's causal attention at the smollm2 cell's shapes
+    runs as the fused kernel on TPU, and its temporaries are a small
+    fraction of the direct softmax gradient's [4,32,2048,2048] tensors."""
+    monkeypatch.setattr(attn, "fused_kernel_backend", lambda: True)
+    monkeypatch.setattr(rules, "_CURRENT_MESH", None)
+    monkeypatch.setenv("SCT_INTERPRET", "0")
+    x = ((TRAIN_B, TRAIN_S, TRAIN_H, TRAIN_HD), jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(*x, sharding=one_chip)] * 3
+
+    attn.reset_attention_path_counts()
+    fused = jax.jit(_train_attention(True, grad)).lower(*args).compile()
+    assert attn.attention_path_counts() == {"fused": 1}
+    assert "tpu_custom_call" in fused.as_text()
+
+    direct = jax.jit(_train_attention(False, True)).lower(*args).compile()
+    fused_temp = fused.memory_analysis().temp_size_in_bytes
+    direct_temp = direct.memory_analysis().temp_size_in_bytes
+    assert fused_temp < direct_temp / 4, (fused_temp, direct_temp)
