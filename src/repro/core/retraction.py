@@ -19,11 +19,26 @@ All retractions are vmappable over leading (layer / expert) axes.
 """
 from __future__ import annotations
 
-from functools import partial
+import collections
+import math
 from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
+
+# factor retractions by method, counted at trace time; a factor stacked
+# over leading (layer / expert) axes counts once per slice
+_COUNTS: collections.Counter = collections.Counter()
+
+
+def retraction_counts() -> dict:
+    """How many factors :func:`retract` has traced, by method, since the
+    last :func:`reset_retraction_counts`."""
+    return dict(_COUNTS)
+
+
+def reset_retraction_counts() -> None:
+    _COUNTS.clear()
 
 
 def _sign_fix(Q: jax.Array, R: jax.Array) -> jax.Array:
@@ -44,10 +59,11 @@ def qr_retract(U: jax.Array) -> jax.Array:
     return _sign_fix(Q, R).astype(orig_dtype)
 
 
-def _cholesky_qr_once(U: jax.Array, axis_name: str | None) -> jax.Array:
+def _cholesky_qr_once(U: jax.Array, axis_name: str | None,
+                      precision=None) -> jax.Array:
     """One CholeskyQR pass: G = U^T U (psum over row shards), R = chol(G)^T,
     U <- U R^{-1}. Communication: k x k, independent of m."""
-    G = jnp.einsum("...mk,...ml->...kl", U, U)
+    G = jnp.einsum("...mk,...ml->...kl", U, U, precision=precision)
     if axis_name is not None:
         G = jax.lax.psum(G, axis_name)
     # G = R^T R with R upper-triangular  =>  chol(G) = R^T (lower)
@@ -72,11 +88,17 @@ def cholesky_qr2_retract(U: jax.Array, axis_name: str | None = None) -> jax.Arra
 
     If ``axis_name`` is given, U is interpreted as row-sharded along that
     mapped axis (inside shard_map) and the Gram matrix is psum'd.
+
+    Both Grams are formed at full fp32 precision: at default precision a
+    TPU rounds the operands to bf16, which left factors 2e-4 to 5e-4 off
+    the manifold after every step on a TPU v5e, where QR leaves ~1e-6.
+    The Cholesky factorization and the triangular solve compile to
+    full-precision products on their own.
     """
     orig_dtype = U.dtype
     Uf = U.astype(jnp.float32)
-    Uf = _cholesky_qr_once(Uf, axis_name)
-    Uf = _cholesky_qr_once(Uf, axis_name)
+    Uf = _cholesky_qr_once(Uf, axis_name, jax.lax.Precision.HIGHEST)
+    Uf = _cholesky_qr_once(Uf, axis_name, jax.lax.Precision.HIGHEST)
     return Uf.astype(orig_dtype)
 
 
@@ -91,7 +113,9 @@ def cayley_retract(U: jax.Array, tangent_scale: float = 1.0) -> jax.Array:
     """
     orig_dtype = U.dtype
     Uf = U.astype(jnp.float32)
-    # Nearest-manifold reference point via one CholeskyQR pass.
+    # Nearest-manifold reference point via one CholeskyQR pass. Its Gram
+    # stays at default precision, which a TPU rounds to bf16 (see
+    # cholesky_qr2_retract).
     Q = _cholesky_qr_once(Uf, None)
     # Tangent direction Delta = U - Q at Q; skew part drives the Cayley map.
     D = (Uf - Q) * tangent_scale
@@ -125,15 +149,17 @@ def retract(U: jax.Array, method: str = "qr", axis_name: str | None = None,
     shard independently and return a factor that is *not* orthonormal
     globally, so those combinations raise instead of corrupting the
     manifold. Extra kwargs go to the method (e.g. ``tangent_scale`` for
-    cayley)."""
-    if method == "cholesky_qr2":
-        return cholesky_qr2_retract(U, axis_name=axis_name, **kwargs)
+    cayley). Each call counts ``prod(U.shape[:-2])`` factors under
+    ``method`` (:func:`retraction_counts`)."""
     fn = RETRACTIONS.get(method)
     if fn is None:
         raise ValueError(f"unknown retraction {method!r}; options {list(RETRACTIONS)}")
-    if axis_name is not None:
+    if axis_name is not None and method != "cholesky_qr2":
         raise ValueError(
             f"retraction {method!r} cannot distribute over axis_name="
             f"{axis_name!r}: per-shard QR/Cayley of a row-sharded factor is "
             f"not globally orthonormal; use method='cholesky_qr2'")
+    _COUNTS[method] += math.prod(U.shape[:-2])
+    if method == "cholesky_qr2":
+        return fn(U, axis_name=axis_name, **kwargs)
     return fn(U, **kwargs)
