@@ -6,7 +6,9 @@ Paper (Algorithm 1, lines 5-7): after each AdamW step,
 
 The sign correction makes the retraction continuous (QR is unique only up
 to column signs; fixing diag(R) > 0 picks the branch closest to the
-pre-update factor).
+pre-update factor). ``qr_retract`` computes that Q by Cholesky-QR2 at full
+fp32 precision and falls back to Householder QR where the factor is too
+ill-conditioned for it.
 
 Beyond-paper (DESIGN.md S2): CholeskyQR2 — the TPU/distributed-native
 retraction. For a row-sharded U only the k x k Gram matrix is
@@ -48,34 +50,73 @@ def _sign_fix(Q: jax.Array, R: jax.Array) -> jax.Array:
     return Q * sign[..., None, :]
 
 
-def qr_retract(U: jax.Array) -> jax.Array:
-    """Paper-faithful QR retraction with sign correction (Eq. 5):
-    ``U (..., m, k) -> Q * sign(diag(R))`` where ``Q, R = qr(U)``. Maps
-    a factor drifted off the Stiefel manifold by an optimizer step back
-    to orthonormal columns; computed in fp32 regardless of storage
-    dtype, broadcast over leading stacked axes."""
-    orig_dtype = U.dtype
-    Q, R = jnp.linalg.qr(U.astype(jnp.float32))
-    return _sign_fix(Q, R).astype(orig_dtype)
-
-
-def _cholesky_qr_once(U: jax.Array, axis_name: str | None,
-                      precision=None) -> jax.Array:
-    """One CholeskyQR pass: G = U^T U (psum over row shards), R = chol(G)^T,
-    U <- U R^{-1}. Communication: k x k, independent of m."""
+def _gram(U: jax.Array, axis_name: str | None, precision) -> jax.Array:
+    """U^T U, psum'd over row shards along ``axis_name``."""
     G = jnp.einsum("...mk,...ml->...kl", U, U, precision=precision)
     if axis_name is not None:
         G = jax.lax.psum(G, axis_name)
+    return G
+
+
+def _cholesky_solve(U: jax.Array, G: jax.Array) -> jax.Array:
+    """U R^{-1} for the upper-triangular R with R^T R = G, U's Gram."""
     # G = R^T R with R upper-triangular  =>  chol(G) = R^T (lower)
     k = G.shape[-1]
     G = G + (1e-10 * jnp.trace(G, axis1=-2, axis2=-1)[..., None, None] / k
              ) * jnp.eye(k, dtype=G.dtype)
     L = jnp.linalg.cholesky(G)
     # Solve U_new L^T = U  =>  U_new = U L^{-T}
-    Un = jax.lax.linalg.triangular_solve(
+    return jax.lax.linalg.triangular_solve(
         L, U, left_side=False, lower=True, transpose_a=True
     )
-    return Un
+
+
+def _cholesky_qr_once(U: jax.Array, axis_name: str | None,
+                      precision=None) -> jax.Array:
+    """One CholeskyQR pass: G = U^T U (psum over row shards), R = chol(G)^T,
+    U <- U R^{-1}. Communication: k x k, independent of m."""
+    return _cholesky_solve(U, _gram(U, axis_name, precision))
+
+
+# Cholesky-QR's first pass is trusted when ||Q1^T Q1 - I||_F is below
+# this: the Frobenius norm bounds the 2-norm, so cond(Q1) < sqrt(3), where
+# the second pass is orthonormal to fp32 rounding (Yamamoto et al., 2015)
+_QR_GUARD = 0.5
+
+
+def _qr_retract(U: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """:func:`qr_retract`, and whether the stacked leaf fell back to
+    Householder QR (an int32 0 or 1)."""
+    orig_dtype = U.dtype
+    Uf = U.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    Q1 = _cholesky_qr_once(Uf, None, hi)
+    G2 = _gram(Q1, None, hi)
+    eye = jnp.eye(G2.shape[-1], dtype=G2.dtype)
+    # one predicate for the whole stack, so that the cond runs one branch;
+    # a NaN or inf from a failed Cholesky compares false and falls back
+    ok = jnp.all(jnp.sum(jnp.square(G2 - eye), axis=(-2, -1)) < _QR_GUARD ** 2)
+    Q = jax.lax.cond(ok, lambda: _cholesky_solve(Q1, G2),
+                     lambda: _sign_fix(*jnp.linalg.qr(Uf)))
+    return Q.astype(orig_dtype), (~ok).astype(jnp.int32)
+
+
+def qr_retract(U: jax.Array) -> jax.Array:
+    """Paper-faithful QR retraction with sign correction (Eq. 5):
+    ``U (..., m, k) -> Q * sign(diag(R))`` where ``Q, R = qr(U)``. Maps
+    a factor drifted off the Stiefel manifold by an optimizer step back
+    to orthonormal columns; computed in fp32 regardless of storage
+    dtype, broadcast over leading stacked axes.
+
+    The Q is computed by Cholesky-QR2 with both Grams at full fp32
+    precision: the same positive-diagonal-R factor as the sign-fixed
+    QR, from matmuls instead of a sequential Householder QR. Where the
+    first pass leaves Q1 too far from orthonormal for the second to
+    repair (``||Q1^T Q1 - I||_F >= 0.5`` in any slice of the stack: a
+    condition number of about 1e4 or more, a rank-deficient or non-finite
+    factor), the whole stacked leaf takes Householder ``jnp.linalg.qr``
+    with the sign fix instead."""
+    return _qr_retract(U)[0]
 
 
 def cholesky_qr2_retract(U: jax.Array, axis_name: str | None = None) -> jax.Array:
@@ -151,6 +192,14 @@ def retract(U: jax.Array, method: str = "qr", axis_name: str | None = None,
     manifold. Extra kwargs go to the method (e.g. ``tangent_scale`` for
     cayley). Each call counts ``prod(U.shape[:-2])`` factors under
     ``method`` (:func:`retraction_counts`)."""
+    return retract_with_fallback(U, method, axis_name, **kwargs)[0]
+
+
+def retract_with_fallback(U: jax.Array, method: str = "qr",
+                          axis_name: str | None = None, **kwargs):
+    """:func:`retract`, and for ``method="qr"`` whether the stacked leaf
+    fell back to Householder QR (an int32 0 or 1 on device; None under
+    any other method)."""
     fn = RETRACTIONS.get(method)
     if fn is None:
         raise ValueError(f"unknown retraction {method!r}; options {list(RETRACTIONS)}")
@@ -160,6 +209,8 @@ def retract(U: jax.Array, method: str = "qr", axis_name: str | None = None,
             f"{axis_name!r}: per-shard QR/Cayley of a row-sharded factor is "
             f"not globally orthonormal; use method='cholesky_qr2'")
     _COUNTS[method] += math.prod(U.shape[:-2])
+    if method == "qr":
+        return _qr_retract(U, **kwargs)
     if method == "cholesky_qr2":
-        return fn(U, axis_name=axis_name, **kwargs)
-    return fn(U, **kwargs)
+        return fn(U, axis_name=axis_name, **kwargs), None
+    return fn(U, **kwargs), None

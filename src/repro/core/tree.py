@@ -7,12 +7,13 @@ params for lax.scan). Retractions broadcast over those axes natively.
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.spectral import is_spectral
-from repro.core.retraction import retract
+from repro.core.retraction import retract_with_fallback
 
 
 def _walk(tree: Any, fn) -> Any:
@@ -29,14 +30,39 @@ def _walk(tree: Any, fn) -> Any:
 def retract_tree(params: Any, method: str = "qr", axis_name: str | None = None) -> Any:
     """Apply Stiefel retraction to U and V of every spectral group in the
     tree (paper Algorithm 1, lines 5-7, over the whole model)."""
+    return retract_tree_with_stats(params, method, axis_name)[0]
+
+
+QR_FALLBACKS = "retraction/qr_fallbacks"
+
+
+def retract_tree_with_stats(params: Any, method: str = "qr",
+                            axis_name: str | None = None) -> Tuple[Any, Dict[str, jax.Array]]:
+    """:func:`retract_tree`, and the retraction's step metrics: where
+    ``qr`` retracted some factor, ``{"retraction/qr_fallbacks": n}`` with
+    n the stacked U and V leaves that fell back to Householder QR (an
+    int32 on device); else ``{}``."""
+    fallbacks = []
 
     def _retract_group(g: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
         out = dict(g)
-        out["U"] = retract(g["U"], method=method, axis_name=axis_name)
-        out["V"] = retract(g["V"], method=method, axis_name=axis_name)
+        for name in ("U", "V"):
+            out[name], fell = retract_with_fallback(g[name], method=method,
+                                                    axis_name=axis_name)
+            if fell is not None:
+                fallbacks.append(fell)
         return out
 
-    return _walk(params, _retract_group)
+    out = _walk(params, _retract_group)
+    return out, ({QR_FALLBACKS: sum(fallbacks)} if fallbacks else {})
+
+
+def skipped_retraction_stats(params: Any, method: str = "qr") -> Dict[str, jax.Array]:
+    """The metrics :func:`retract_tree_with_stats` gives ``params`` under
+    ``method``, for a step that retracts nothing: the same keys, 0."""
+    groups = []
+    _walk(params, lambda g: groups.append(g) or g)
+    return {QR_FALLBACKS: jnp.zeros((), jnp.int32)} if method == "qr" and groups else {}
 
 
 def spectral_leaf_mask(params: Any) -> Any:
@@ -62,7 +88,6 @@ def spectral_leaf_mask(params: Any) -> Any:
 def max_orthogonality_error(params: Any) -> jax.Array:
     """Max ortho error over all spectral factors in the tree (diagnostic,
     matches the paper's Table 2 'Ortho. Error' row)."""
-    import jax.numpy as jnp
     from repro.core.manifold import orthogonality_error
 
     errs = []

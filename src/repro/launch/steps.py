@@ -17,7 +17,7 @@ from repro.config.model_config import ModelConfig
 from repro.config.shapes import ShapeSpec, input_specs
 from repro.core.precision import effective_policy, scale_loss
 from repro.models.model import init_model, train_loss, prefill, decode_step
-from repro.optim import make_sct_optimizer, SCTOptimizer
+from repro.optim import make_sct_optimizer, reporting_metrics_to, SCTOptimizer
 from repro.sharding.rules import param_pspecs, set_current_mesh, constrain, dp_axes
 from repro.sharding.partition import (
     batch_pspecs,
@@ -50,7 +50,11 @@ def make_train_step(cfg: ModelConfig, optimizer: Optional[SCTOptimizer] = None,
     ``telemetry=True`` folds the spectral-rank summary (rank/telemetry.py:
     effective rank, energy capture, tail mass, Stiefel drift — all
     computed on the post-update factors inside the same jit) into the
-    metrics dict under ``rank/*`` keys; dense models emit nothing."""
+    metrics dict under ``rank/*`` keys; dense models emit nothing.
+
+    Where some spectral factor is retracted by ``qr``, the metrics hold
+    ``retraction/qr_fallbacks``: the stacked U and V leaves whose
+    Cholesky-QR2 guard sent them to Householder QR this step."""
     opt = optimizer or make_sct_optimizer(cfg)
     # always a concrete policy: the legacy precision mode resolves
     # to (cfg.dtype compute, fp32 accum, no scaling) instead of a None
@@ -96,8 +100,10 @@ def make_train_step(cfg: ModelConfig, optimizer: Optional[SCTOptimizer] = None,
             grads = jax.tree.map(lambda g: g / microbatches, grads)
             loss = jnp.mean(losses)
             metrics = jax.tree.map(lambda m: jnp.mean(m), mets)
-        new_state = opt.apply(state, grads)
-        metrics = dict(metrics)
+        opt_metrics = {}
+        with reporting_metrics_to(opt_metrics):
+            new_state = opt.apply(state, grads)
+        metrics = dict(metrics, **opt_metrics)
         if scaling:
             # report the unscaled loss (scale is a power of two: exact)
             metrics["loss"] = loss / scale

@@ -11,6 +11,7 @@ from repro.optim.clip import global_norm, clip_by_global_norm
 from repro.optim.sct_optimizer import (
     SCTOptimizer,
     make_sct_optimizer,
+    reporting_metrics_to,
     TrainState,
 )
 
@@ -28,6 +29,7 @@ __all__ = [
     "global_norm",
     "clip_by_global_norm",
     "SCTOptimizer",
+    "reporting_metrics_to",
     "make_sct_optimizer",
     "TrainState",
 ]

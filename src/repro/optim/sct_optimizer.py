@@ -17,6 +17,8 @@ forward casts down from).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 from typing import Any, Optional, Union
 
@@ -33,12 +35,29 @@ from repro.core.precision import (
     unscale_grads,
 )
 from repro.core import scopes
-from repro.core.tree import retract_tree
+from repro.core.tree import retract_tree_with_stats, skipped_retraction_stats
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.optim.clip import clip_by_global_norm
 from repro.optim.schedule import ScheduleConfig, make_schedule
 
 TrainState = dict  # {"params", "opt", "step"[, "loss_scale"]}
+
+# where SCTOptimizer.apply reports its step metrics while a step is traced
+_METRICS_SINK: contextvars.ContextVar = contextvars.ContextVar("sct_step_metrics",
+                                                              default=None)
+
+
+@contextlib.contextmanager
+def reporting_metrics_to(sink: dict):
+    """Within the block, :meth:`SCTOptimizer.apply` adds its step metrics
+    (``core/tree.py:retract_tree_with_stats``; 0 on a step that retracts
+    nothing) to ``sink``: arrays of the trace that calls ``apply``, so
+    read them in that same trace."""
+    token = _METRICS_SINK.set(sink)
+    try:
+        yield sink
+    finally:
+        _METRICS_SINK.reset(token)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,7 +84,8 @@ class SCTOptimizer:
 
     # ------------------------------------------------------------------
     def _update(self, params: Any, opt: Any, step: jax.Array, grads: Any):
-        """One AdamW step + (conditional) retraction. ``step`` is the
+        """One AdamW step + (conditional) retraction, and the retraction's
+        metrics (``core/tree.py:retract_tree_with_stats``). ``step`` is the
         pre-increment counter: the schedule reads it, the retraction
         cadence checks step+1 — both exactly as the fp32 path always did."""
         with jax.named_scope(scopes.OPTIMIZER):
@@ -75,15 +95,17 @@ class SCTOptimizer:
             params, opt = adamw_update(params, grads, opt, self.adamw, lr_t)
         with jax.named_scope(scopes.RETRACTION):
             if self.retract_every == 1:
-                params = retract_tree(params, self.retraction, self.retract_axis_name)
+                params, stats = retract_tree_with_stats(params, self.retraction,
+                                                        self.retract_axis_name)
             else:
-                params = jax.lax.cond(
+                params, stats = jax.lax.cond(
                     (step + 1) % self.retract_every == 0,
-                    lambda p: retract_tree(p, self.retraction, self.retract_axis_name),
-                    lambda p: p,
+                    lambda p: retract_tree_with_stats(p, self.retraction,
+                                                      self.retract_axis_name),
+                    lambda p: (p, skipped_retraction_stats(p, self.retraction)),
                     params,
                 )
-        return params, opt
+        return params, opt, stats
 
     def resize(self, key: jax.Array, state: TrainState, target) -> TrainState:
         """Resize every spectral group in the TrainState to ``target``
@@ -97,27 +119,36 @@ class SCTOptimizer:
         return resize_train_state(key, state, target, retraction=self.retraction)
 
     def apply(self, state: TrainState, grads: Any) -> TrainState:
+        """One optimizer step; its metrics go to the sink of an enclosing
+        :func:`reporting_metrics_to`."""
+        state, metrics = self._apply(state, grads)
+        sink = _METRICS_SINK.get()
+        if sink is not None:
+            sink.update(metrics)
+        return state
+
+    def _apply(self, state: TrainState, grads: Any):
         pol = self.precision
         # both the step builder (which scales the loss) and this unscale
         # path key on policy AND state, so a checkpoint written under a
         # different precision policy degrades to the unscaled path on
         # both sides instead of scaling on one side only
         if pol is None or not pol.loss_scaling or "loss_scale" not in state:
-            params, opt = self._update(state["params"], state["opt"],
-                                       state["step"], grads)
+            params, opt, stats = self._update(state["params"], state["opt"],
+                                              state["step"], grads)
             out = dict(state)
             out.update(params=params, opt=opt, step=state["step"] + 1)
-            return out
+            return out, stats
 
         # mixed path: grads arrive scaled by state["loss_scale"]["scale"]
         ls = state["loss_scale"]
         with jax.named_scope(scopes.OPTIMIZER):
             grads = unscale_grads(grads, ls)
             finite = all_finite(grads)
-        params, opt = jax.lax.cond(
+        params, opt, stats = jax.lax.cond(
             finite,
             lambda p, o, g: self._update(p, o, state["step"], g),
-            lambda p, o, g: (p, o),
+            lambda p, o, g: (p, o, skipped_retraction_stats(p, self.retraction)),
             state["params"], state["opt"], grads,
         )
         # the step counter advances even on a skip: the data stream and
@@ -125,7 +156,7 @@ class SCTOptimizer:
         out = dict(state)   # preserve any extra TrainState entries
         out.update(params=params, opt=opt, step=state["step"] + 1,
                    loss_scale=loss_scale_update(ls, finite, pol))
-        return out
+        return out, stats
 
 
 def make_sct_optimizer(

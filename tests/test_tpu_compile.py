@@ -6,7 +6,9 @@ compiler, so a block shape it refuses (the tiling rule on the last two
 block dimensions) or a VMEM budget it cannot meet passes every
 interpret-mode test. Here each kernel is lowered and compiled at
 published widths for one chip of a described ``v5e:2x2`` topology, and
-the compiled program must call the kernel (``tpu_custom_call``).
+the compiled program must call the kernel (``tpu_custom_call``). The
+guarded ``qr`` retraction is compiled the same way at the cell's factor
+shapes.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process at a time may load the TPU library, and
@@ -17,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.retraction import qr_retract
 from repro.kernels.paged_decode import (
     paged_gqa_decode_cold_pallas,
     paged_gqa_decode_pallas,
@@ -43,6 +46,9 @@ MLA_H, MLA_LAT, MLA_ROPE = 16, 512, 64
 MLP_M, MLP_N, MLP_K, MLP_TOKENS = 2048, 8192, 128, 512
 # smollm2-1.7b training attention: 4 packed rows of 2048, 32 heads of 64
 TRAIN_B, TRAIN_S, TRAIN_H, TRAIN_HD = 4, 2048, 32, 64
+# smollm2-1.7b's MLP factors as the train step retracts them: 24 layers
+# stacked, rank 128
+FACTOR_LAYERS, FACTOR_K = 24, 128
 
 
 @pytest.fixture(scope="module")
@@ -160,3 +166,12 @@ def test_fused_train_attention_compiles(one_chip, grad, monkeypatch):
     fused_temp = fused.memory_analysis().temp_size_in_bytes
     direct_temp = direct.memory_analysis().temp_size_in_bytes
     assert fused_temp < direct_temp / 4, (fused_temp, direct_temp)
+
+
+@pytest.mark.parametrize("rows", [MLP_M, MLP_N])
+def test_qr_retraction_compiles(one_chip, rows):
+    """The guarded ``qr`` retraction of a stacked factor compiles with both
+    of its branches, Cholesky-QR2 and the Householder fallback, behind
+    one conditional."""
+    text = _compiled_text(qr_retract, one_chip, ((FACTOR_LAYERS, rows, FACTOR_K), jnp.float32))
+    assert text.count("conditional(") == 1
