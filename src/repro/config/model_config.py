@@ -80,11 +80,9 @@ class ModelConfig:
     # --- numerics / runtime ---
     dtype: str = "bfloat16"              # compute dtype (params fp32 master)
     remat: bool = True
-    use_pallas: bool = False
     max_seq: int = 4096
-    # sequence-parallel layer-boundary activations (measured win for
-    # dense families; conflicts with the MoE shard_map x-layout, see
-    # EXPERIMENTS.md §Perf) — set per arch config
+    # sequence-parallel layer-boundary activations; read only by the
+    # dry-run's launch/steps.py:lower_step — set per arch config
     seq_parallel: bool = False
 
     # ------------------------------------------------------------------

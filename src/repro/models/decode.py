@@ -17,13 +17,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.model_config import ModelConfig
+from repro.models import blocks
 from repro.nn import attention as attn
 from repro.nn import mamba as mamba_mod
 from repro.nn import xlstm as xlstm_mod
 from repro.nn.embedding import apply_embedding, apply_lm_head
-from repro.nn.mlp import apply_mlp
-from repro.nn.moe import apply_moe
-from repro.models.lm import _norm_apply, _compute_dtype
 
 Params = Dict[str, Any]
 
@@ -207,118 +205,81 @@ def lm_init_paged_state(cfg: ModelConfig, pcfg, cold_kv: str = "none"):
 
 
 # ======================================================================
-# Prefill
+# Layer scans
 # ======================================================================
 
-def _attn_prefill(cfg, p, h, positions, cache):
-    if cfg.attention == "mla":
-        return attn.apply_mla_prefill(p, h, cfg, positions=positions, cache=cache)
-    return attn.apply_gqa_prefill(p, h, cfg, positions=positions, cache=cache,
-                                  use_pallas=cfg.use_pallas)
+def _scan_layers(params: Params, x: jax.Array, state, cfg: ModelConfig,
+                 attend, mamba, mlstm, slstm):
+    """Family-dispatched layer scan over the stacked params and their
+    per-layer state, through the bodies of models/blocks.py. The mode's
+    mixers each map ``(layer params, h, state) -> (out, new state)``:
+    ``attend`` for attention (its state is the layer's KV cache),
+    ``mamba``, ``mlstm`` and ``slstm`` for the recurrent mixers."""
+    if cfg.family in ("dense_lm", "moe_lm"):
+        def f(h, xs):
+            layer_p, cache = xs
+            h, cache, _ = blocks.dense_block(cfg, layer_p, h, attend, cache)
+            return h, cache
+
+        stacks = [("layers", "cache")] if cfg.family == "dense_lm" else (
+            ([("dense_layers", "dense_cache")] if cfg.first_dense_layers else [])
+            + [("moe_layers", "moe_cache")]
+        )
+        new_state = dict(state)
+        for pk, ck in stacks:
+            x, new_state[ck] = jax.lax.scan(f, x, (params[pk], state[ck]))
+        return x, new_state
+    if cfg.family == "hybrid":
+        def f(h, xs):
+            period_p, cache, mstates = xs
+            h, cache, mstates, _ = blocks.hybrid_period(
+                cfg, period_p, h, attend, mamba, cache, mstates)
+            return h, (cache, mstates)
+
+        x, (cache, mstates) = jax.lax.scan(
+            f, x, (params["periods"], state["attn_cache"], state["mamba"]))
+        return x, {"attn_cache": cache, "mamba": mstates}
+    if cfg.family == "ssm_lm":
+        def f(h, xs):
+            period_p, mstates, sstate = xs
+            h, mstates, sstate = blocks.xlstm_period(
+                cfg, period_p, h, mlstm, slstm, mstates, sstate)
+            return h, (mstates, sstate)
+
+        x, (mstates, sstate) = jax.lax.scan(
+            f, x, (params["periods"], state["mlstm"], state["slstm"]))
+        return x, {"mlstm": mstates, "slstm": sstate}
+    raise ValueError(cfg.family)
 
 
-def _dense_block_prefill(cfg, p, x, positions, cache):
-    h = _norm_apply(cfg, p["attn_norm"], x)
-    h, cache = _attn_prefill(cfg, p["attn"], h, positions, cache)
-    x = x + h
-    h = _norm_apply(cfg, p["mlp_norm"], x)
-    body = p.get("mlp")
-    if body is not None:
-        h = apply_mlp(body, h, act=cfg.act, use_pallas=cfg.use_pallas)
-    else:
-        h, _ = apply_moe(p["moe"], h, cfg, capacity_factor=cfg.capacity_factor,
-                         use_pallas=cfg.use_pallas)
-    return x + h, cache
-
+# ======================================================================
+# Prefill
+# ======================================================================
 
 def prefill_lm(params: Params, tokens: jax.Array, cfg: ModelConfig, state):
     """Process the prompt, fill caches. Returns (last-token logits, state).
 
-    For hybrid/ssm families the prefill runs the training forward for
-    outputs and reconstructs the recurrent state from a final single-step
-    replay (exact for attention caches; SSM/xlstm prefill states are
-    produced by their scan's final carry).
+    Attention layers fill their caches; Mamba and mLSTM layers run their
+    training scan and keep its exact final carry; sLSTM replays its cell
+    scan for the final state.
     """
     b, s = tokens.shape
-    dt = _compute_dtype(cfg)
+    dt = blocks.compute_dtype(cfg)
     x = apply_embedding(params["embed"], tokens, compute_dtype=dt)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
-    if cfg.family in ("dense_lm", "moe_lm"):
-        stacks = []
-        if cfg.family == "dense_lm":
-            stacks = [("layers", "cache")]
-        else:
-            if cfg.first_dense_layers:
-                stacks.append(("dense_layers", "dense_cache"))
-            stacks.append(("moe_layers", "moe_cache"))
-        new_state = dict(state)
-        for pk, ck in stacks:
-            def f(carry, xs):
-                layer_p, cache = xs
-                h, cache = _dense_block_prefill(cfg, layer_p, carry, positions, cache)
-                return h, cache
+    def attend(p, h, cache):
+        if cfg.attention == "mla":
+            return attn.apply_mla_prefill(p, h, cfg, positions=positions, cache=cache)
+        return attn.apply_gqa_prefill(p, h, cfg, positions=positions, cache=cache)
 
-            x, new_cache = jax.lax.scan(f, x, (params[pk], state[ck]))
-            new_state[ck] = new_cache
-        state = new_state
-    elif cfg.family == "hybrid":
-        def f(carry, xs):
-            period_p, cache, mstates = xs
-            h = carry
-            new_m = []
-            for p in range(cfg.attn_every):
-                lp = period_p[f"p{p}"]
-                hh = _norm_apply(cfg, lp["pre_norm"], h)
-                if "attn" in lp:
-                    hh, cache = attn.apply_gqa_prefill(
-                        lp["attn"], hh, cfg, positions=positions, cache=cache,
-                        use_pallas=cfg.use_pallas)
-                else:
-                    mi = p if p < cfg.attn_offset else p - 1
-                    hh, ms = _mamba_prefill(lp["mamba"], hh, cfg,
-                                            jax.tree.map(lambda t: t[mi], mstates))
-                    new_m.append(ms)
-                h = h + hh
-                hh = _norm_apply(cfg, lp["ff_norm"], h)
-                if "moe" in lp:
-                    hh, _ = apply_moe(lp["moe"], hh, cfg, capacity_factor=cfg.capacity_factor,
-                                      use_pallas=cfg.use_pallas)
-                else:
-                    hh = apply_mlp(lp["mlp"], hh, act=cfg.act, use_pallas=cfg.use_pallas)
-                h = h + hh
-            mstacked = jax.tree.map(lambda *xs: jnp.stack(xs), *new_m)
-            return h, (cache, mstacked)
-
-        x, (new_cache, new_mamba) = jax.lax.scan(
-            f, x, (params["periods"], state["attn_cache"], state["mamba"])
-        )
-        state = {"attn_cache": new_cache, "mamba": new_mamba}
-    elif cfg.family == "ssm_lm":
-        def f(carry, xs):
-            period_p, mstates, sstate = xs
-            h = carry
-            new_m = []
-            new_s = sstate
-            for p in range(cfg.slstm_every):
-                lp = period_p[f"p{p}"]
-                hh = _norm_apply(cfg, lp["pre_norm"], h)
-                if "slstm" in lp:
-                    hh, new_s = _slstm_prefill(lp["slstm"], hh, cfg)
-                else:
-                    mi = p if p < cfg.slstm_offset else p - 1
-                    hh, ms = _mlstm_prefill(lp["mlstm"], hh, cfg)
-                    new_m.append(ms)
-                h = h + hh
-            mstacked = jax.tree.map(lambda *xs: jnp.stack(xs), *new_m)
-            return h, (mstacked, new_s)
-
-        x, (new_m, new_s) = jax.lax.scan(f, x, (params["periods"], state["mlstm"], state["slstm"]))
-        state = {"mlstm": new_m, "slstm": new_s}
-    else:
-        raise ValueError(cfg.family)
-
-    x = _norm_apply(cfg, params["final_norm"], x[:, -1:, :])
+    x, state = _scan_layers(
+        params, x, state, cfg, attend,
+        mamba=lambda p, h, st: _mamba_prefill(p, h, cfg, st),
+        # the chunkwise mLSTM returns its outputs and the exact final state
+        mlstm=lambda p, h, st: xlstm_mod.apply_mlstm_with_state(p, h, cfg),
+        slstm=lambda p, h, st: _slstm_prefill(p, h, cfg))
+    x = blocks.norm_apply(cfg, params["final_norm"], x[:, -1:, :])
     logits = apply_lm_head(params["embed"], x)
     return logits, state
 
@@ -330,11 +291,6 @@ def _mamba_prefill(p, x, cfg, state):
         "conv": new["conv"].astype(state["conv"].dtype),
         "ssm": new["ssm"].astype(state["ssm"].dtype),
     }
-
-
-def _mlstm_prefill(p, x, cfg):
-    # chunkwise form returns outputs AND the exact final recurrent state
-    return xlstm_mod.apply_mlstm_with_state(p, x, cfg)
 
 
 def _slstm_prefill(p, x, cfg):
@@ -367,8 +323,7 @@ def decode_step_lm(params: Params, tokens: jax.Array, state, cache_len: jax.Arra
     def attn_decode(p, h, cache):
         if cfg.attention == "mla":
             return attn.apply_mla_decode(p, h, cfg, cache=cache, cache_len=cache_len)
-        return attn.apply_gqa_decode(p, h, cfg, cache=cache, cache_len=cache_len,
-                                     use_pallas=cfg.use_pallas)
+        return attn.apply_gqa_decode(p, h, cfg, cache=cache, cache_len=cache_len)
 
     return _decode_step_body(params, tokens, state, cfg, attn_decode)
 
@@ -396,8 +351,7 @@ def decode_step_lm_paged(params: Params, tokens: jax.Array, state,
                 tp_axis=tp_axis, tp_size=tp_size, cold_flags=cold_flags)
         return attn.apply_gqa_decode_paged(
             p, h, cfg, cache=cache, block_table=block_table, seq_lens=seq_lens,
-            use_pallas=cfg.use_pallas, tp_axis=tp_axis, tp_size=tp_size,
-            cold_flags=cold_flags)
+            tp_axis=tp_axis, tp_size=tp_size, cold_flags=cold_flags)
 
     return _decode_step_body(params, tokens, state, cfg, attn_decode)
 
@@ -428,99 +382,23 @@ def prefill_chunk_lm_paged(params: Params, tokens: jax.Array, state,
                 tp_axis=tp_axis, tp_size=tp_size, cold_flags=cold_flags)
         return attn.apply_gqa_prefill_paged(
             p, h, cfg, cache=cache, block_table=block_table, start=start,
-            use_pallas=cfg.use_pallas, tp_axis=tp_axis, tp_size=tp_size,
-            cold_flags=cold_flags)
+            tp_axis=tp_axis, tp_size=tp_size, cold_flags=cold_flags)
 
     return _decode_step_body(params, tokens, state, cfg, attn_chunk)
 
 
 def _decode_step_body(params: Params, tokens: jax.Array, state, cfg: ModelConfig,
                       attn_decode):
-    """Family-dispatched layer scan shared by the static and paged steps;
-    ``attn_decode(layer_params, h, cache) -> (out, cache)`` is the
-    layout-specific part."""
-    dt = _compute_dtype(cfg)
+    """The layer scan shared by the static and paged steps and the paged
+    chunked prefill; ``attn_decode(layer_params, h, cache) -> (out,
+    cache)`` is the layout-specific part."""
+    dt = blocks.compute_dtype(cfg)
     x = apply_embedding(params["embed"], tokens, compute_dtype=dt)
-
-    if cfg.family in ("dense_lm", "moe_lm"):
-        stacks = [("layers", "cache")] if cfg.family == "dense_lm" else (
-            ([("dense_layers", "dense_cache")] if cfg.first_dense_layers else [])
-            + [("moe_layers", "moe_cache")]
-        )
-        new_state = dict(state)
-        for pk, ck in stacks:
-            def f(carry, xs):
-                layer_p, cache = xs
-                h = _norm_apply(cfg, layer_p["attn_norm"], carry)
-                h, cache = attn_decode(layer_p["attn"], h, cache)
-                hx = carry + h
-                h = _norm_apply(cfg, layer_p["mlp_norm"], hx)
-                if "mlp" in layer_p:
-                    h = apply_mlp(layer_p["mlp"], h, act=cfg.act, use_pallas=cfg.use_pallas)
-                else:
-                    h, _ = apply_moe(layer_p["moe"], h, cfg,
-                                     capacity_factor=cfg.capacity_factor,
-                                     use_pallas=cfg.use_pallas)
-                return hx + h, cache
-
-            x, new_cache = jax.lax.scan(f, x, (params[pk], state[ck]))
-            new_state[ck] = new_cache
-        state = new_state
-    elif cfg.family == "hybrid":
-        def f(carry, xs):
-            period_p, cache, mstates = xs
-            h = carry
-            new_m = []
-            for p in range(cfg.attn_every):
-                lp = period_p[f"p{p}"]
-                hh = _norm_apply(cfg, lp["pre_norm"], h)
-                if "attn" in lp:
-                    hh, cache = attn_decode(lp["attn"], hh, cache)
-                else:
-                    mi = p if p < cfg.attn_offset else p - 1
-                    hh, ms = mamba_mod.apply_mamba_decode(
-                        lp["mamba"], hh, cfg, state=jax.tree.map(lambda t: t[mi], mstates))
-                    new_m.append(ms)
-                h = h + hh
-                hh = _norm_apply(cfg, lp["ff_norm"], h)
-                if "moe" in lp:
-                    hh, _ = apply_moe(lp["moe"], hh, cfg,
-                                      capacity_factor=cfg.capacity_factor,
-                                      use_pallas=cfg.use_pallas)
-                else:
-                    hh = apply_mlp(lp["mlp"], hh, act=cfg.act, use_pallas=cfg.use_pallas)
-                h = h + hh
-            mstacked = jax.tree.map(lambda *xs: jnp.stack(xs), *new_m)
-            return h, (cache, mstacked)
-
-        x, (new_cache, new_m) = jax.lax.scan(
-            f, x, (params["periods"], state["attn_cache"], state["mamba"]))
-        state = {"attn_cache": new_cache, "mamba": new_m}
-    elif cfg.family == "ssm_lm":
-        def f(carry, xs):
-            period_p, mstates, sstate = xs
-            h = carry
-            new_m = []
-            new_s = sstate
-            for p in range(cfg.slstm_every):
-                lp = period_p[f"p{p}"]
-                hh = _norm_apply(cfg, lp["pre_norm"], h)
-                if "slstm" in lp:
-                    hh, new_s = xlstm_mod.apply_slstm_decode(lp["slstm"], hh, cfg, state=sstate)
-                else:
-                    mi = p if p < cfg.slstm_offset else p - 1
-                    hh, ms = xlstm_mod.apply_mlstm_decode(
-                        lp["mlstm"], hh, cfg, state=jax.tree.map(lambda t: t[mi], mstates))
-                    new_m.append(ms)
-                h = h + hh
-            mstacked = jax.tree.map(lambda *xs: jnp.stack(xs), *new_m)
-            return h, (mstacked, new_s)
-
-        x, (new_m, new_s) = jax.lax.scan(f, x, (params["periods"], state["mlstm"], state["slstm"]))
-        state = {"mlstm": new_m, "slstm": new_s}
-    else:
-        raise ValueError(cfg.family)
-
-    x = _norm_apply(cfg, params["final_norm"], x)
+    x, state = _scan_layers(
+        params, x, state, cfg, attn_decode,
+        mamba=lambda p, h, st: mamba_mod.apply_mamba_decode(p, h, cfg, state=st),
+        mlstm=lambda p, h, st: xlstm_mod.apply_mlstm_decode(p, h, cfg, state=st),
+        slstm=lambda p, h, st: xlstm_mod.apply_slstm_decode(p, h, cfg, state=st))
+    x = blocks.norm_apply(cfg, params["final_norm"], x)
     logits = apply_lm_head(params["embed"], x)
     return logits, state

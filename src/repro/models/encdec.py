@@ -16,7 +16,8 @@ from repro.nn import attention as attn
 from repro.nn.embedding import init_embedding, apply_embedding, apply_lm_head
 from repro.nn.mlp import init_mlp, apply_mlp
 from repro.nn.norms import init_layernorm, apply_layernorm
-from repro.models.lm import cross_entropy, _compute_dtype
+from repro.models.blocks import compute_dtype, remat_scan
+from repro.models.lm import cross_entropy
 
 Params = Dict[str, Any]
 
@@ -60,57 +61,52 @@ def init_encdec(key, cfg: ModelConfig) -> Params:
 
 def encode(params, frames: jax.Array, cfg: ModelConfig) -> jax.Array:
     """frames: (b, n_frames, d) precomputed embeddings (conv stub)."""
-    dt = _compute_dtype(cfg)
+    dt = compute_dtype(cfg)
     s = frames.shape[1]
     x = frames.astype(dt) + params["enc_pos"]["w"][:s].astype(dt)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], frames.shape[:2])
 
     def f(carry, layer_p):
         h = apply_layernorm(layer_p["attn_norm"], carry)
-        h = attn.apply_gqa(layer_p["attn"], h, cfg, positions=positions, causal=False,
-                           use_pallas=cfg.use_pallas)
+        h = attn.apply_gqa(layer_p["attn"], h, cfg, positions=positions, causal=False)
         x2 = carry + h
         h = apply_layernorm(layer_p["mlp_norm"], x2)
-        h = apply_mlp(layer_p["mlp"], h, act="gelu", use_pallas=cfg.use_pallas)
+        h = apply_mlp(layer_p["mlp"], h, act="gelu")
         return x2 + h, None
 
-    if cfg.remat:
-        f = jax.checkpoint(f, policy=jax.checkpoint_policies.nothing_saveable)
-    x, _ = jax.lax.scan(f, x, params["enc_layers"])
+    x, _ = remat_scan(f, x, params["enc_layers"], cfg)
     return apply_layernorm(params["enc_norm"], x)
 
 
-def _dec_block(cfg, layer_p, x, enc_out, positions, cache=None, cache_len=None):
+def _dec_block(cfg, layer_p, x, enc_out, attend, cache=None):
+    """Self-attention, cross-attention over ``enc_out``, MLP; the
+    mode's ``attend(attn_params, h, cache) -> (out, cache)``."""
     h = apply_layernorm(layer_p["attn_norm"], x)
-    if cache is None:
-        h = attn.apply_gqa(layer_p["attn"], h, cfg, positions=positions,
-                           use_pallas=cfg.use_pallas)
-    else:
-        h, cache = attn.apply_gqa_decode(layer_p["attn"], h, cfg, cache=cache,
-                                         cache_len=cache_len, use_pallas=cfg.use_pallas)
+    h, cache = attend(layer_p["attn"], h, cache)
     x = x + h
     h = apply_layernorm(layer_p["xattn_norm"], x)
     h = attn.apply_cross_attn(layer_p["xattn"], h, enc_out, cfg)
     x = x + h
     h = apply_layernorm(layer_p["mlp_norm"], x)
-    h = apply_mlp(layer_p["mlp"], h, act="gelu", use_pallas=cfg.use_pallas)
+    h = apply_mlp(layer_p["mlp"], h, act="gelu")
     return x + h, cache
 
 
 def decode_train(params, tokens, enc_out, cfg) -> jax.Array:
-    dt = _compute_dtype(cfg)
+    dt = compute_dtype(cfg)
     b, s = tokens.shape
     x = apply_embedding(params["embed"], tokens, compute_dtype=dt)
     x = x + params["dec_pos"]["w"][:s].astype(dt)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
+    def attend(p, h, cache):
+        return attn.apply_gqa(p, h, cfg, positions=positions), cache
+
     def f(carry, layer_p):
-        out, _ = _dec_block(cfg, layer_p, carry, enc_out, positions)
+        out, _ = _dec_block(cfg, layer_p, carry, enc_out, attend)
         return out, None
 
-    if cfg.remat:
-        f = jax.checkpoint(f, policy=jax.checkpoint_policies.nothing_saveable)
-    x, _ = jax.lax.scan(f, x, params["dec_layers"])
+    x, _ = remat_scan(f, x, params["dec_layers"], cfg)
     x = apply_layernorm(params["final_norm"], x)
     return apply_lm_head(params["embed"], x)
 
@@ -134,24 +130,18 @@ def encdec_state_specs(cfg, batch, max_seq):
 def prefill_encdec(params, tokens, cfg, state, encoder_frames):
     """Encode audio + run the decoder prompt, filling self-attn cache."""
     enc_out = encode(params, encoder_frames, cfg)
-    dt = _compute_dtype(cfg)
+    dt = compute_dtype(cfg)
     b, s = tokens.shape
     x = apply_embedding(params["embed"], tokens, compute_dtype=dt)
     x = x + params["dec_pos"]["w"][:s].astype(dt)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
+    def attend(p, h, cache):
+        return attn.apply_gqa_prefill(p, h, cfg, positions=positions, cache=cache)
+
     def f(carry, xs):
         layer_p, cache = xs
-        h = apply_layernorm(layer_p["attn_norm"], carry)
-        h, cache = attn.apply_gqa_prefill(layer_p["attn"], h, cfg, positions=positions,
-                                          cache=cache, use_pallas=cfg.use_pallas)
-        x2 = carry + h
-        h = apply_layernorm(layer_p["xattn_norm"], x2)
-        h = attn.apply_cross_attn(layer_p["xattn"], h, enc_out, cfg)
-        x2 = x2 + h
-        h = apply_layernorm(layer_p["mlp_norm"], x2)
-        h = apply_mlp(layer_p["mlp"], h, act="gelu", use_pallas=cfg.use_pallas)
-        return x2 + h, cache
+        return _dec_block(cfg, layer_p, carry, enc_out, attend, cache)
 
     x, new_cache = jax.lax.scan(f, x, (params["dec_layers"], state["cache"]))
     x = apply_layernorm(params["final_norm"], x[:, -1:, :])
@@ -159,17 +149,18 @@ def prefill_encdec(params, tokens, cfg, state, encoder_frames):
 
 
 def decode_step_encdec(params, tokens, state, cache_len, cfg, encoder_out):
-    dt = _compute_dtype(cfg)
+    dt = compute_dtype(cfg)
     b = tokens.shape[0]
     x = apply_embedding(params["embed"], tokens, compute_dtype=dt)
     pos_emb = jnp.take(params["dec_pos"]["w"].astype(dt), cache_len[None], axis=0)
     x = x + pos_emb[None]
 
+    def attend(p, h, cache):
+        return attn.apply_gqa_decode(p, h, cfg, cache=cache, cache_len=cache_len)
+
     def f(carry, xs):
         layer_p, cache = xs
-        out, cache = _dec_block(cfg, layer_p, carry, encoder_out,
-                                positions=None, cache=cache, cache_len=cache_len)
-        return out, cache
+        return _dec_block(cfg, layer_p, carry, encoder_out, attend, cache)
 
     x, new_cache = jax.lax.scan(f, x, (params["dec_layers"], state["cache"]))
     x = apply_layernorm(params["final_norm"], x)

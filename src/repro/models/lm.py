@@ -8,12 +8,13 @@
 Homogeneous layer stacks are scanned (lax.scan over stacked params) with
 optional remat; heterogeneous families scan over their repeat *period*
 (jamba: 8 layers, xlstm: 8 blocks) so the HLO stays small at 32-80
-layers. SCT spectral layers appear wherever the config says so; the
-dense (m, n) matrices of converted layers never exist.
+layers. The layer bodies live in models/blocks.py, shared with the
+serving paths (models/decode.py); this module holds init, the training
+forward and the loss. SCT spectral layers appear wherever the config
+says so; the dense (m, n) matrices of converted layers never exist.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Tuple
 
 import jax
@@ -21,18 +22,21 @@ import jax.numpy as jnp
 
 from repro.config.model_config import ModelConfig
 from repro.core import scopes
+from repro.models.blocks import (
+    compute_dtype,
+    dense_block,
+    hybrid_period,
+    norm_apply,
+    remat_scan,
+    xlstm_period,
+)
 from repro.nn import attention as attn
 from repro.nn import mamba as mamba_mod
 from repro.nn import xlstm as xlstm_mod
 from repro.nn.embedding import init_embedding, apply_embedding, apply_lm_head
-from repro.nn.mlp import init_mlp, apply_mlp
-from repro.nn.moe import init_moe, apply_moe
-from repro.nn.norms import (
-    init_rmsnorm,
-    apply_rmsnorm,
-    init_layernorm,
-    apply_layernorm,
-)
+from repro.nn.mlp import init_mlp
+from repro.nn.moe import init_moe
+from repro.nn.norms import init_rmsnorm, init_layernorm
 from repro.sharding.rules import constrain_activation
 
 Params = Dict[str, Any]
@@ -41,14 +45,6 @@ Params = Dict[str, Any]
 def _norm_init(cfg, dim=None):
     dim = dim or cfg.d_model
     return init_rmsnorm(dim) if cfg.norm == "rmsnorm" else init_layernorm(dim)
-
-
-def _norm_apply(cfg, p, x):
-    return apply_rmsnorm(p, x) if cfg.norm == "rmsnorm" else apply_layernorm(p, x)
-
-
-def _compute_dtype(cfg):
-    return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
 
 
 # ======================================================================
@@ -164,143 +160,65 @@ def init_lm(key, cfg: ModelConfig) -> Params:
 # Forward (training / no-cache)
 # ======================================================================
 
-def _attention_residual(cfg, p, x, positions):
-    with jax.named_scope(scopes.ATTENTION):
-        h = _norm_apply(cfg, p["attn_norm"], x)
-        if cfg.attention == "mla":
-            h = attn.apply_mla(p["attn"], h, cfg, positions=positions)
-        else:
-            h = attn.apply_gqa(p["attn"], h, cfg, positions=positions,
-                               use_pallas=cfg.use_pallas)
-        return x + h
-
-
-def _dense_block(cfg, p, x, positions):
-    x = _attention_residual(cfg, p, x, positions)
-    with jax.named_scope(scopes.MLP):
-        h = _norm_apply(cfg, p["mlp_norm"], x)
-        h = apply_mlp(p["mlp"], h, act=cfg.act, use_pallas=cfg.use_pallas)
-        return x + h
-
-
-def _moe_block(cfg, p, x, positions):
-    x = _attention_residual(cfg, p, x, positions)
-    with jax.named_scope(scopes.MLP):
-        h = _norm_apply(cfg, p["mlp_norm"], x)
-        h, aux = apply_moe(p["moe"], h, cfg, capacity_factor=cfg.capacity_factor,
-                           use_pallas=cfg.use_pallas)
-        return x + h, aux
-
-
-def _hybrid_period_fwd(cfg, pp, x, positions):
-    aux_total = jnp.float32(0.0)
-    for p in range(cfg.attn_every):
-        lp = pp[f"p{p}"]
-        if "attn" in lp:
-            with jax.named_scope(scopes.ATTENTION):
-                h = _norm_apply(cfg, lp["pre_norm"], x)
-                h = attn.apply_gqa(lp["attn"], h, cfg, positions=positions,
-                                   use_pallas=cfg.use_pallas)
-                x = x + h
-        else:
-            h = _norm_apply(cfg, lp["pre_norm"], x)
-            x = x + mamba_mod.apply_mamba(lp["mamba"], h, cfg)
-        with jax.named_scope(scopes.MLP):
-            h = _norm_apply(cfg, lp["ff_norm"], x)
-            if "moe" in lp:
-                h, aux = apply_moe(lp["moe"], h, cfg, capacity_factor=cfg.capacity_factor,
-                                   use_pallas=cfg.use_pallas)
-                aux_total = aux_total + aux
-            else:
-                h = apply_mlp(lp["mlp"], h, act=cfg.act, use_pallas=cfg.use_pallas)
-            x = x + h
-    return x, aux_total
-
-
-def _xlstm_period_fwd(cfg, pp, x, positions):
-    for p in range(cfg.slstm_every):
-        lp = pp[f"p{p}"]
-        h = _norm_apply(cfg, lp["pre_norm"], x)
-        if "slstm" in lp:
-            h = xlstm_mod.apply_slstm(lp["slstm"], h, cfg)
-        else:
-            h = xlstm_mod.apply_mlstm(lp["mlstm"], h, cfg)
-        x = x + h
-    return x
-
-
-def _scan_stack(stacked_params, x, body, cfg):
-    """lax.scan over the leading layer axis of stacked params, with
-    optional remat of the body (activation recompute in backward)."""
-
-    def f(carry, layer_p):
-        return constrain_activation(body(layer_p, carry)), None
-
-    if cfg.remat:
-        f = jax.checkpoint(f, policy=jax.checkpoint_policies.nothing_saveable)
-    out, _ = jax.lax.scan(f, x, stacked_params)
-    return out
+def _stateless(fn):
+    # a training mixer threads no state through the shared layer bodies
+    return lambda p, h, state: (fn(p, h), state)
 
 
 def forward_lm(params: Params, tokens: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     """tokens (b, s) -> (logits (b, s, vocab) fp32-castable, aux_loss)."""
     b, s = tokens.shape
-    dt = _compute_dtype(cfg)
+    dt = compute_dtype(cfg)
     with jax.named_scope(scopes.EMBED):
         x = constrain_activation(apply_embedding(params["embed"], tokens, compute_dtype=dt))
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     aux = jnp.float32(0.0)
 
+    if cfg.attention == "mla":
+        attend = _stateless(lambda p, h: attn.apply_mla(p, h, cfg, positions=positions))
+    else:
+        attend = _stateless(lambda p, h: attn.apply_gqa(p, h, cfg, positions=positions))
+
+    def dense_layer(h, layer_p):
+        h, _, _ = dense_block(cfg, layer_p, h, attend)
+        return constrain_activation(h), None
+
+    def moe_layer(carry, layer_p):
+        h, aux = carry
+        h, _, a = dense_block(cfg, layer_p, h, attend)
+        return (constrain_activation(h), aux + a), None
+
+    def hybrid_layer(carry, period_p):
+        h, aux = carry
+        h, _, _, a = hybrid_period(
+            cfg, period_p, h, attend,
+            _stateless(lambda p, h: mamba_mod.apply_mamba(p, h, cfg)))
+        return (constrain_activation(h), aux + a), None
+
+    def xlstm_layer(h, period_p):
+        h, _, _ = xlstm_period(
+            cfg, period_p, h,
+            _stateless(lambda p, h: xlstm_mod.apply_mlstm(p, h, cfg)),
+            _stateless(lambda p, h: xlstm_mod.apply_slstm(p, h, cfg)))
+        return constrain_activation(h), None
+
     if cfg.family == "dense_lm":
-        x = _scan_stack(
-            params["layers"], x,
-            lambda p, h: _dense_block(cfg, p, h, positions), cfg,
-        )
+        x, _ = remat_scan(dense_layer, x, params["layers"], cfg)
     elif cfg.family == "moe_lm":
         if "dense_layers" in params:
-            x = _scan_stack(
-                params["dense_layers"], x,
-                lambda p, h: _dense_block(cfg, p, h, positions), cfg,
-            )
-        x, aux = _scan_moe(params["moe_layers"], x, cfg, positions)
+            x, _ = remat_scan(dense_layer, x, params["dense_layers"], cfg)
+        (x, aux), _ = remat_scan(moe_layer, (x, aux), params["moe_layers"], cfg)
     elif cfg.family == "hybrid":
-        x, aux = _scan_hybrid(params["periods"], x, cfg, positions)
+        (x, aux), _ = remat_scan(hybrid_layer, (x, aux), params["periods"], cfg)
     elif cfg.family == "ssm_lm":
-        x = _scan_stack(
-            params["periods"], x,
-            lambda p, h: _xlstm_period_fwd(cfg, p, h, positions), cfg,
-        )
+        x, _ = remat_scan(xlstm_layer, x, params["periods"], cfg)
     else:
         raise ValueError(cfg.family)
 
     with jax.named_scope(scopes.HEAD):
-        x = _norm_apply(cfg, params["final_norm"], x)
+        x = norm_apply(cfg, params["final_norm"], x)
         logits = apply_lm_head(params["embed"], x)
     return logits, aux
-
-
-def _scan_moe(stacked, x, cfg, positions):
-    def f(carry, layer_p):
-        h, aux = carry
-        h, a = _moe_block(cfg, layer_p, h, positions)
-        return (constrain_activation(h), aux + a), None
-
-    if cfg.remat:
-        f = jax.checkpoint(f, policy=jax.checkpoint_policies.nothing_saveable)
-    (x, aux), _ = jax.lax.scan(f, (x, jnp.float32(0.0)), stacked)
-    return x, aux
-
-
-def _scan_hybrid(stacked, x, cfg, positions):
-    def f(carry, period_p):
-        h, aux = carry
-        h, a = _hybrid_period_fwd(cfg, period_p, h, positions)
-        return (constrain_activation(h), aux + a), None
-
-    if cfg.remat:
-        f = jax.checkpoint(f, policy=jax.checkpoint_policies.nothing_saveable)
-    (x, aux), _ = jax.lax.scan(f, (x, jnp.float32(0.0)), stacked)
-    return x, aux
 
 
 # ======================================================================

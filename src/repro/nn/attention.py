@@ -369,12 +369,12 @@ def init_gqa(key, cfg, dtype=jnp.float32):
     }
 
 
-def _gqa_qkv(p, x, cfg, positions, use_pallas=False):
+def _gqa_qkv(p, x, cfg, positions):
     b, s, _ = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = apply_linear(p["wq"], x, use_pallas=use_pallas).reshape(b, s, h, hd)
-    k = apply_linear(p["wk"], x, use_pallas=use_pallas).reshape(b, s, kvh, hd)
-    v = apply_linear(p["wv"], x, use_pallas=use_pallas).reshape(b, s, kvh, hd)
+    q = apply_linear(p["wq"], x).reshape(b, s, h, hd)
+    k = apply_linear(p["wk"], x).reshape(b, s, kvh, hd)
+    v = apply_linear(p["wv"], x).reshape(b, s, kvh, hd)
     if cfg.rope == "rope":
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -385,12 +385,12 @@ def _gqa_qkv(p, x, cfg, positions, use_pallas=False):
     return q, k, v
 
 
-def apply_gqa(p, x, cfg, *, positions, causal=True, use_pallas=False):
+def apply_gqa(p, x, cfg, *, positions, causal=True):
     """Training / no-cache forward."""
     b, s, _ = x.shape
-    q, k, v = _gqa_qkv(p, x, cfg, positions, use_pallas)
+    q, k, v = _gqa_qkv(p, x, cfg, positions)
     o = _sdpa(q, k, v, causal=causal)
-    return apply_linear(p["wo"], o.reshape(b, s, -1), use_pallas=use_pallas)
+    return apply_linear(p["wo"], o.reshape(b, s, -1))
 
 
 def gqa_init_cache(cfg, batch, max_seq, dtype=jnp.bfloat16):
@@ -401,25 +401,25 @@ def gqa_init_cache(cfg, batch, max_seq, dtype=jnp.bfloat16):
     }
 
 
-def apply_gqa_prefill(p, x, cfg, *, positions, cache, use_pallas=False):
+def apply_gqa_prefill(p, x, cfg, *, positions, cache):
     """Fill cache[:, :s] and return outputs (causal)."""
     b, s, _ = x.shape
-    q, k, v = _gqa_qkv(p, x, cfg, positions, use_pallas)
+    q, k, v = _gqa_qkv(p, x, cfg, positions)
     cache = {
         "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)),
         "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)),
     }
     o = _sdpa(q, k, v, causal=True)
-    return apply_linear(p["wo"], o.reshape(b, s, -1), use_pallas=use_pallas), cache
+    return apply_linear(p["wo"], o.reshape(b, s, -1)), cache
 
 
-def apply_gqa_decode(p, x, cfg, *, cache, cache_len, use_pallas=False):
+def apply_gqa_decode(p, x, cfg, *, cache, cache_len):
     """One-token step. x: (b, 1, d); cache_len: scalar int32 (tokens
     already in cache). Attends over the full cache with a validity mask
     — S stays static so the step compiles once."""
     b, s, _ = x.shape
     positions = jnp.broadcast_to(cache_len[None, None], (b, s)).astype(jnp.int32)
-    q, k, v = _gqa_qkv(p, x, cfg, positions, use_pallas)
+    q, k, v = _gqa_qkv(p, x, cfg, positions)
     ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), cache_len, axis=1)
     cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), cache_len, axis=1)
     S = ck.shape[1]
@@ -434,7 +434,7 @@ def apply_gqa_decode(p, x, cfg, *, cache, cache_len, use_pallas=False):
     o = _sdpa(q.astype(jnp.float32), ck.astype(jnp.float32),
               cv.astype(jnp.float32), causal=False,
               kv_len_mask=valid).astype(q.dtype)
-    return apply_linear(p["wo"], o.reshape(b, s, -1), use_pallas=use_pallas), {"k": ck, "v": cv}
+    return apply_linear(p["wo"], o.reshape(b, s, -1)), {"k": ck, "v": cv}
 
 
 def _gather_cold(cache, name, block_table, cold_flags):
@@ -463,7 +463,7 @@ def _gather_cold(cache, name, block_table, cold_flags):
     return jnp.where(flag, deq, g.astype(jnp.float32))
 
 
-def apply_gqa_prefill_paged(p, x, cfg, *, cache, block_table, start, use_pallas=False,
+def apply_gqa_prefill_paged(p, x, cfg, *, cache, block_table, start,
                             tp_axis=None, tp_size=1, cold_flags=None):
     """Chunked prefill from a logical offset against a paged pool.
 
@@ -487,7 +487,7 @@ def apply_gqa_prefill_paged(p, x, cfg, *, cache, block_table, start, use_pallas=
 
     b, c, _ = x.shape
     positions = jnp.broadcast_to(start + jnp.arange(c, dtype=jnp.int32)[None], (b, c))
-    q, k, v = _gqa_qkv(p, x, cfg, positions, use_pallas)
+    q, k, v = _gqa_qkv(p, x, cfg, positions)
     if tp_axis is not None:
         q = _tp_slice(q, tp_axis, cfg.n_heads // tp_size, 2)
         k = _tp_slice(k, tp_axis, cfg.n_kv_heads // tp_size, 2)
@@ -500,10 +500,10 @@ def apply_gqa_prefill_paged(p, x, cfg, *, cache, block_table, start, use_pallas=
     o = _sdpa(q, ck.astype(q.dtype), cv.astype(q.dtype), causal=True, q_offset=start)
     if tp_axis is not None:
         o = jax.lax.all_gather(o, tp_axis, axis=2, tiled=True)
-    return apply_linear(p["wo"], o.reshape(b, c, -1), use_pallas=use_pallas), new_cache
+    return apply_linear(p["wo"], o.reshape(b, c, -1)), new_cache
 
 
-def apply_gqa_decode_paged(p, x, cfg, *, cache, block_table, seq_lens, use_pallas=False,
+def apply_gqa_decode_paged(p, x, cfg, *, cache, block_table, seq_lens,
                            tp_axis=None, tp_size=1, cold_flags=None):
     """One-token step against a paged pool (serving/paged_cache.py).
 
@@ -535,7 +535,7 @@ def apply_gqa_decode_paged(p, x, cfg, *, cache, block_table, seq_lens, use_palla
     b, s, _ = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = seq_lens[:, None].astype(jnp.int32)
-    q, k, v = _gqa_qkv(p, x, cfg, positions, use_pallas)
+    q, k, v = _gqa_qkv(p, x, cfg, positions)
     if tp_axis is not None:
         h, kvh = h // tp_size, kvh // tp_size
         q = _tp_slice(q, tp_axis, h, 2)
@@ -566,7 +566,7 @@ def apply_gqa_decode_paged(p, x, cfg, *, cache, block_table, seq_lens, use_palla
                   kv_len_mask=valid).astype(q.dtype)
     if tp_axis is not None:
         o = jax.lax.all_gather(o, tp_axis, axis=2, tiled=True)
-    return apply_linear(p["wo"], o.reshape(b, s, -1), use_pallas=use_pallas), new_cache
+    return apply_linear(p["wo"], o.reshape(b, s, -1)), new_cache
 
 
 # ---------------------------------------------------------------- MLA ----

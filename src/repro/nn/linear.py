@@ -54,31 +54,19 @@ def init_linear(
     return p
 
 
-def apply_linear(p, x: jax.Array, *, use_pallas: bool = False) -> jax.Array:
-    """Dispatch on parameterization. The dense (m, n) matrix is never
-    built in the spectral branch. Int8-quantized spectral groups
-    (serving/quantize.py) route to the fused q8 kernel under
-    ``use_pallas`` — int8 factors are consumed directly, no dequantized
-    fp factor exists; the non-Pallas branches dequantize on the fly
-    (int8 lives in HBM, the fp copy is a per-call transient)."""
+def apply_linear(p, x: jax.Array) -> jax.Array:
+    """Dispatch on the form of the parameters: spectral, int8 spectral,
+    int8 dense or dense. The dense (m, n) matrix is never built in the
+    spectral branch. Int8 groups (serving/quantize.py) are dequantized
+    on the fly: int8 lives in HBM, the fp copy is a per-call transient."""
     if is_spectral(p):
-        if use_pallas:
-            from repro.kernels.ops import spectral_matmul
-
-            y = spectral_matmul(x, p["U"], p["s"], p["V"])
-        else:
-            y = spectral_apply(p, x)
+        y = spectral_apply(p, x)
     elif _is_q8_spectral(p):                    # int8 spectral group
-        if use_pallas:
-            from repro.kernels.ops import spectral_matmul_q8
+        from repro.serving.quantize import dequantize_int8
 
-            y = spectral_matmul_q8(x, p["U"], p["s"], p["V"])
-        else:
-            from repro.serving.quantize import dequantize_int8
-
-            y = spectral_apply(
-                {"U": dequantize_int8(p["U"], x.dtype), "s": p["s"],
-                 "V": dequantize_int8(p["V"], x.dtype)}, x)
+        y = spectral_apply(
+            {"U": dequantize_int8(p["U"], x.dtype), "s": p["s"],
+             "V": dequantize_int8(p["V"], x.dtype)}, x)
     elif _is_q8_dense(p):                       # int8 dense weight
         from repro.serving.quantize import dequantize_int8
 

@@ -21,13 +21,13 @@ def init_mlp(key, d_model: int, d_ff: int, *, rank=None, act: str = "swiglu",
     return p
 
 
-def apply_mlp(p, x: jax.Array, *, act: str = "swiglu", use_pallas: bool = False) -> jax.Array:
-    up = apply_linear(p["up"], x, use_pallas=use_pallas)
+def apply_mlp(p, x: jax.Array, *, act: str = "swiglu") -> jax.Array:
+    up = apply_linear(p["up"], x)
     if act == "swiglu":
-        gate = apply_linear(p["gate"], x, use_pallas=use_pallas)
+        gate = apply_linear(p["gate"], x)
         h = jax.nn.silu(gate) * up
     elif act == "gelu":
         h = jax.nn.gelu(up)
     else:
         raise ValueError(act)
-    return apply_linear(p["down"], h, use_pallas=use_pallas)
+    return apply_linear(p["down"], h)

@@ -1,7 +1,7 @@
 """Mixture-of-Experts with capacity-bounded sort-free dispatch, shared
 experts (deepseek), and SCT inside every expert.
 
-Dispatch strategy (TPU-native, DESIGN.md S5): instead of the dense
+Dispatch strategy (TPU-native): instead of the dense
 one-hot dispatch einsum (FLOPs = tokens x E x d — would dwarf the real
 compute), tokens are scattered into an (E, C, d) buffer using positions
 computed with a cumsum over the top-k assignment mask, processed with a
@@ -57,7 +57,7 @@ def _expert_matmul(p, x):
     """x: (G, E, C, d) @ expert weights -> (G, E, C, n). Spectral experts
     never materialize (E, d, n); with U/V long axes FSDP-sharded the
     cross-shard reduction payload is the RANK axis (G,E,C,k) — the
-    spectral-TP collective win (DESIGN.md S5)."""
+    spectral-TP collective win."""
     if is_spectral(p):
         h = jnp.einsum("gecd,edk->geck", x, p["U"].astype(x.dtype))
         h = h * p["s"][None, :, None, :].astype(x.dtype)
@@ -65,10 +65,8 @@ def _expert_matmul(p, x):
     return jnp.einsum("gecd,edn->gecn", x, p["w"].astype(x.dtype))
 
 
-def apply_moe_sharded(p, x, cfg, *, capacity_factor: float = 1.25,
-                      use_pallas: bool = False):
-    """Explicit shard_map MoE (EXPERIMENTS.md §Perf, deepseek hillclimb
-    iteration 2). Device (i, j) on the (data, model) mesh holds tokens-
+def apply_moe_sharded(p, x, cfg, *, capacity_factor: float = 1.25):
+    """Explicit shard_map MoE. Device (i, j) on the (data, model) mesh holds tokens-
     shard-i and experts-shard-j; it dispatches ITS tokens to ITS experts
     locally (zero-communication dispatch), so the only collectives are:
 
@@ -184,7 +182,7 @@ def apply_moe_sharded(p, x, cfg, *, capacity_factor: float = 1.25,
     if cfg.n_shared_experts:
         from repro.nn.mlp import apply_mlp
 
-        out = out + apply_mlp(p["shared"], x, act="swiglu", use_pallas=use_pallas)
+        out = out + apply_mlp(p["shared"], x, act="swiglu")
     return out, aux
 
 
@@ -222,15 +220,14 @@ def _sharded_moe_ok(cfg, b, s):
     )
 
 
-def apply_moe(p, x, cfg, *, capacity_factor: float = 1.25, use_pallas: bool = False):
+def apply_moe(p, x, cfg, *, capacity_factor: float = 1.25):
     """x: (b, s, d) -> (b, s, d), plus the load-balance aux loss.
 
     Dispatches to the explicit shard_map implementation under a mesh
     (apply_moe_sharded); the pure-jnp path below is the single-device /
     fallback reference the tests validate against.
 
-    Hierarchical LOCAL-CAPACITY dispatch (EXPERIMENTS.md §Perf, the
-    deepseek hillclimb): tokens are grouped by their data shard; the
+    Hierarchical LOCAL-CAPACITY dispatch: tokens are grouped by their data shard; the
     capacity cumsum, scatter and gather-back are all group-local (no
     collective), and the single cross-shard movement is the
     (data-major -> expert-major) buffer transpose, which GSPMD lowers to
@@ -238,8 +235,7 @@ def apply_moe(p, x, cfg, *, capacity_factor: float = 1.25, use_pallas: bool = Fa
     (C_loc = C/n_dp), as production MoE systems do."""
     b, s_len, d = x.shape
     if _sharded_moe_ok(cfg, b, s_len):
-        return apply_moe_sharded(p, x, cfg, capacity_factor=capacity_factor,
-                                 use_pallas=use_pallas)
+        return apply_moe_sharded(p, x, cfg, capacity_factor=capacity_factor)
     s = s_len
     E, K = cfg.n_experts, cfg.top_k
     T = b * s
@@ -305,5 +301,5 @@ def apply_moe(p, x, cfg, *, capacity_factor: float = 1.25, use_pallas: bool = Fa
     if cfg.n_shared_experts:
         from repro.nn.mlp import apply_mlp
 
-        out = out + apply_mlp(p["shared"], xg, act="swiglu", use_pallas=use_pallas)
+        out = out + apply_mlp(p["shared"], xg, act="swiglu")
     return out.reshape(b, s, d), aux_loss

@@ -51,7 +51,7 @@ def test_fused_sdpa_equals_direct(on_tpu, heads, dtype, s, d, remat):
     def fused(q, k, v):
         return at._sdpa(q, k, v, causal=True)
 
-    if remat:   # as models/lm.py:_scan_stack runs each layer
+    if remat:   # as models/blocks.py:remat_scan runs each layer
         fused = jax.checkpoint(fused, policy=jax.checkpoint_policies.nothing_saveable)
 
     def grads(fn):
