@@ -73,10 +73,15 @@ def spectral_apply(params: SpectralParams, x: jax.Array) -> jax.Array:
     (PrecisionPolicy.compute_dtype via cfg.dtype) while the stored
     masters keep their own dtype — the apply-time-cast contract.
     """
-    U, s, V = params["U"], params["s"], params["V"]
-    h = x @ U.astype(x.dtype)        # (..., k)   cost O(b m k)
-    h = h * s.astype(h.dtype)        # (..., k)   cost O(b k)
-    return h @ V.T.astype(x.dtype)   # (..., n)   cost O(b k n)
+    h = spectral_project(params, x)             # (..., k)
+    return h @ params["V"].T.astype(x.dtype)    # (..., n)   cost O(b k n)
+
+
+def spectral_project(params: SpectralParams, x: jax.Array) -> jax.Array:
+    """The rank-space half of :func:`spectral_apply`: ``(x @ U) * s``,
+    (..., k), in ``x.dtype``."""
+    h = x @ params["U"].astype(x.dtype)      # (..., k)   cost O(b m k)
+    return h * params["s"].astype(h.dtype)   # (..., k)   cost O(b k)
 
 
 def spectral_param_count(m: int, n: int, k: int) -> int:

@@ -34,6 +34,7 @@ from repro.kernels.spectral_matmul import (
 )
 from repro.kernels.spectral_matmul_q8 import spectral_matmul_q8_pallas
 from repro.nn import attention as attn
+from repro.nn import mlp
 from repro.sharding import rules
 
 # serving geometry of chip_smoke.py: 4 slots, page 16, 32 pages/seq
@@ -46,6 +47,10 @@ MLA_H, MLA_LAT, MLA_ROPE = 16, 512, 64
 MLP_M, MLP_N, MLP_K, MLP_TOKENS = 2048, 8192, 128, 512
 # smollm2-1.7b training attention: 4 packed rows of 2048, 32 heads of 64
 TRAIN_B, TRAIN_S, TRAIN_H, TRAIN_HD = 4, 2048, 32, 64
+# smollm2-1.7b's training MLP: 8192 tokens a step, d_ff 8192, rank 128
+MLP_STEP_TOKENS, MLP_FF = 8192, 8192
+# one d_ff-wide bf16 tensor of that MLP (gate, up, h or a gradient)
+MLP_WIDE_BYTES = MLP_STEP_TOKENS * MLP_FF * 2
 # smollm2-1.7b's MLP factors as the train step retracts them: 24 layers
 # stacked, rank 128
 FACTOR_LAYERS, FACTOR_K = 24, 128
@@ -175,3 +180,65 @@ def test_qr_retraction_compiles(one_chip, rows):
     one conditional."""
     text = _compiled_text(qr_retract, one_chip, ((FACTOR_LAYERS, rows, FACTOR_K), jnp.float32))
     assert text.count("conditional(") == 1
+
+
+def _mlp_grad(layers: int):
+    """Loss and gradient of ``layers`` spectral SwiGLU MLPs under the train
+    step's remat (``models/blocks.py:remat_scan``)."""
+    def loss(p, x):
+        body = lambda h, lp: (h + mlp.apply_mlp(lp, h), None)
+        body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
+        if layers == 1:
+            h = body(x, p)[0]
+        else:
+            h = jax.lax.scan(body, x, p)[0]
+        return jnp.sum(h.astype(jnp.float32) ** 2)
+
+    return jax.value_and_grad(loss, argnums=(0, 1))
+
+
+def _mlp_args(one_chip, layers: int):
+    stack = () if layers == 1 else (layers,)
+    f32 = lambda *s: jax.ShapeDtypeStruct((*stack, *s), jnp.float32, sharding=one_chip)
+    wide = lambda: {"U": f32(MLP_M, MLP_K), "s": f32(MLP_K), "V": f32(MLP_FF, MLP_K)}
+    p = {"gate": wide(), "up": wide(),
+         "down": {"U": f32(MLP_FF, MLP_K), "s": f32(MLP_K), "V": f32(MLP_M, MLP_K)}}
+    x = jax.ShapeDtypeStruct((MLP_STEP_TOKENS, MLP_M), jnp.bfloat16, sharding=one_chip)
+    return p, x
+
+
+def _custom_calls(computation: str, name: str) -> int:
+    return len([line for line in computation.splitlines()
+                if line.lstrip().startswith(f"%{name}") and "custom-call(" in line])
+
+
+def test_fused_mlp_compiles(one_chip, monkeypatch):
+    """The train step's spectral MLP at the smollm2 cell's widths runs as
+    the fused kernels on TPU: no d_ff-wide tensor in the program, where
+    the XLA path holds four at its peak; in a rematerialized layer scan
+    the backward loop calls the backward kernel and no forward kernel."""
+    monkeypatch.setattr(rules, "_CURRENT_MESH", None)
+    monkeypatch.setenv("SCT_INTERPRET", "0")
+    args = _mlp_args(one_chip, 1)
+
+    monkeypatch.setattr(mlp, "fused_kernel_backend", lambda: True)
+    mlp.reset_mlp_path_counts()
+    fused = jax.jit(_mlp_grad(1)).lower(*args).compile()
+    assert mlp.mlp_path_counts() == {"fused": 1}
+    text = fused.as_text()
+    assert "tpu_custom_call" in text
+    assert f"[{MLP_STEP_TOKENS},{MLP_FF}]" not in text
+    scan = jax.jit(_mlp_grad(2)).lower(*_mlp_args(one_chip, 2)).compile().as_text()
+    monkeypatch.setattr(mlp, "fused_kernel_backend", lambda: False)
+    xla = jax.jit(_mlp_grad(1)).lower(*args).compile()
+    assert f"[{MLP_STEP_TOKENS},{MLP_FF}]" in xla.as_text()
+    fused_temp = fused.memory_analysis().temp_size_in_bytes
+    xla_temp = xla.memory_analysis().temp_size_in_bytes
+    # the XLA path holds four d_ff-wide tensors at its peak, the fused
+    # path less than a quarter of one (its d_model-wide temporaries)
+    assert xla_temp >= 4 * MLP_WIDE_BYTES, xla_temp
+    assert fused_temp < MLP_WIDE_BYTES / 4, fused_temp
+
+    bodies = [c for c in scan.split("\n\n") if _custom_calls(c, "sct_mlp_bwd")]
+    assert len(bodies) == 1 and _custom_calls(bodies[0], "sct_mlp_fwd") == 0
+    assert _custom_calls(scan, "sct_mlp_fwd") == 1
